@@ -219,6 +219,9 @@ def main(argv=None) -> None:
     parser.add_argument("--config", help="path to JSON host config")
     parser.add_argument("--name", default=None, help="override silo name")
     args = parser.parse_args(argv)
+    from orleans_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     config: Dict[str, Any] = {}
     if args.config:

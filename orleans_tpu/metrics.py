@@ -452,7 +452,7 @@ declare("engine.phase_s", KIND_HISTOGRAM, "seconds",
         "profiler's log2 histograms mirrored per phase)")
 declare("compile.events", KIND_COUNTER, "compiles",
         "cause-coded compile/retrace events (label 'cause' = the "
-        "tensor/profiler.py churn taxonomy: new_method, bucket_growth, "
+        "tensor/profiler.py churn cause list: new_method, bucket_growth, "
         "shape_change, epoch_mismatch, generation_repack, config_toggle, "
         "mesh_reshard, new_window, cross_shard)")
 declare("compile.lowering_s", KIND_COUNTER, "seconds",

@@ -1477,8 +1477,13 @@ def _serve_main(args) -> int:
         gc.collect()
         gc.freeze()
         gc.set_threshold(100_000, 50, 50)
+        import jax
+
         print(json.dumps({
             "ok": True, "name": silo.name,
+            # the platform this process's engine runs on, so numbers
+            # harvested from it never read as another process's device
+            "platform": jax.devices()[0].platform,
             "gateway_port": silo.gateway_port,
             "table_service_port": (table_service.address[1]
                                    if table_service is not None else 0),

@@ -703,7 +703,7 @@ class GrainArena:
         """Gather the given rows' state columns to host.  All gathers
         dispatch first, then ONE ``jax.device_get`` fetches the whole
         tree — the per-field d2h round-trips (each paying a completion
-        observation on tunneled runtimes) collapse into one.  Gathers
+        observation) collapse into one.  Gathers
         are pow2-padded (row 0 repeated, sliced off after the fetch) so
         data-dependent row counts reuse O(log n) compiled gathers."""
         n = len(rows)

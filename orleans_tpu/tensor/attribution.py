@@ -545,15 +545,22 @@ class WorkloadAttribution:
         row-lifecycle mutation of the accumulators."""
         if not self._pending:
             return
-        if not jax.core.trace_state_clean():
+        pending, self._pending = self._pending, []
+
+        def traced(result) -> bool:
             # under an ACTIVE trace (fused window trace, AOT lower,
             # discovery eval_shape) a jit call inlines into the outer
             # trace and returns TRACERS — storing those would poison
             # the accumulators for every later concrete call.  Defer:
             # the pre-run device_state_in / the next concrete read
             # flushes (traces only need avals, and shapes don't move).
-            return
-        pending, self._pending = self._pending, []
+            # The trace state cannot change inside this call, so only
+            # the FIRST kernel result can be a tracer, before any store.
+            if not isinstance(result, jax.core.Tracer):
+                return False
+            self._pending = pending + self._pending
+            return True
+
         groups: Dict[Tuple, List] = {}
         order: List[Tuple] = []
         for e in pending:
@@ -585,10 +592,13 @@ class WorkloadAttribution:
                     counts, cms, slots = _apply_coalesced(
                         counts, cms, self._slot_arr(), d[0], d[1],
                         self._slot_scalar(slot), d[2], jnp.int32(1))
+                    if traced(counts):
+                        return
                     self._counts[type_name] = counts
                     self._cms[type_name] = cms
                     self._slot_counts = slots
                 continue
+            stale = None
             if checked:
                 k = len(entries)
                 pad = pow2ceil(k)
@@ -605,12 +615,15 @@ class WorkloadAttribution:
                     plan_rows, plan_valid, cdelta, sdelta, n,
                     self._seed_arr(), self._slot_scalar(slot),
                     rows_stack, valid_stack, real)
-                self._stale = stale
             else:
                 counts, cms, slots = _apply_coalesced(
                     counts, cms, self._slot_arr(), cdelta, sdelta,
                     self._slot_scalar(slot), n,
                     jnp.int32(len(entries)))
+            if traced(counts):
+                return
+            if stale is not None:
+                self._stale = stale
             self._counts[type_name] = counts
             self._cms[type_name] = cms
             self._slot_counts = slots

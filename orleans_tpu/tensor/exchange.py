@@ -602,8 +602,6 @@ class ShardExchange:
         compare + masks), cross lanes drop into redelivery, and the
         output width equals the input width — an aligned or all-local
         batch pays nothing for having the exchange in its program."""
-        from jax.experimental.shard_map import shard_map
-
         n = self.n_shards
         axis = self.axis
         m_pad = n * L
@@ -717,9 +715,10 @@ class ShardExchange:
         sharded = P(axis)
         out_specs = (sharded, sharded, sharded, sharded) \
             + (sharded,) * len(leaves)
-        fn = shard_map(per_shard, mesh=self.mesh,
-                       in_specs=(sharded, sharded) + (sharded,) * len(leaves),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(
+            per_shard, mesh=self.mesh,
+            in_specs=(sharded, sharded) + (sharded,) * len(leaves),
+            out_specs=out_specs, check_vma=False)
         recv_rows, recv_mask, dropped, stats, *recv_leaves = fn(
             rows, mask, *leaves)
         # counts SUM across shards; the per-dest demand reduces BOTH
@@ -749,8 +748,6 @@ class ShardExchange:
         fill prefix ranks the receiver takes lanes in, so an overflow
         lane parks into the standard redelivery net instead of being
         silently truncated."""
-        from jax.experimental.shard_map import shard_map
-
         n = self.n_shards
         axis = self.axis
         m_pad = n * L
@@ -848,9 +845,10 @@ class ShardExchange:
         sharded = P(axis)
         out_specs = (sharded, sharded, sharded, sharded) \
             + (sharded,) * len(leaves)
-        fn = shard_map(per_shard, mesh=self.mesh,
-                       in_specs=(sharded, sharded) + (sharded,) * len(leaves),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(
+            per_shard, mesh=self.mesh,
+            in_specs=(sharded, sharded) + (sharded,) * len(leaves),
+            out_specs=out_specs, check_vma=False)
         recv_rows, recv_mask, dropped, stats, *recv_leaves = fn(
             rows, mask, *leaves)
         stats = jnp.concatenate([jnp.sum(stats[:, :3], axis=0),
@@ -968,7 +966,7 @@ class ShardExchange:
             if seen:
                 # same batch shape, new cap: the occupancy estimate
                 # re-quantized the bucket — attribute the recompile
-                # (tensor/profiler.py churn taxonomy) so a flapping
+                # (tensor/profiler.py churn cause list) so a flapping
                 # estimate can never hide as organic shape churn
                 from orleans_tpu.tensor.profiler import CAUSE_BUCKET_GROWTH
                 self.engine.compile_tracker.record(

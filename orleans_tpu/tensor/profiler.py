@@ -28,7 +28,7 @@ Three pieces:
   ships with its own profile.  ``silo.capture_profile(ticks=N)`` is the
   explicit management entry point.
 * **CompileTracker** — every tracked retrace/compile records a CAUSE
-  code (the churn taxonomy below) plus its lowering wall time, into a
+  code (the churn cause list below) plus its lowering wall time, into a
   cause-coded counter family and a bounded ring of recent compile
   events.  This replaces the bare ``compile_count()`` int as the
   cross-silo health number: "13 compiles" becomes "13 compiles: 9
@@ -339,7 +339,7 @@ class TickPhaseProfiler:
 # compile-churn attribution
 # ---------------------------------------------------------------------------
 
-#: the churn taxonomy: every tracked retrace site names ONE of these
+#: the churn cause list: every tracked retrace site names ONE of these
 #: (tests/test_profiler.py lints the call sites against this tuple)
 CAUSE_NEW_METHOD = "new_method"            # first compile of a (type, method)
 CAUSE_BUCKET_GROWTH = "bucket_growth"      # host batch crossed a padding rung

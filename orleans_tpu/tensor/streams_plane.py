@@ -385,10 +385,11 @@ class DeviceSubscriptions:
         promotes the plane back to the fast path on the next publish."""
         if self._bound_keys is None:
             return None
-        if jax.core.trace_state_clean() is False and (
-                self._pull_dirty or self._pull is None):
-            # never rebuild under an active trace: lookup_rows and the
-            # jnp.asarray mirrors would be trace-local
+        if (self._pull_dirty or self._pull is None) \
+                and isinstance(jnp.int32(0), jax.core.Tracer):
+            # never rebuild under an active trace (where even a fresh
+            # constant is a tracer): lookup_rows and the jnp.asarray
+            # mirrors would be trace-local
             return None
         if self._pull_dirty or self._pull is None \
                 or self._pull_stamp != (arena.generation,

@@ -270,6 +270,10 @@ async def run_presence_load_fused(engine, n_players: int = 100_000,
         "messages_per_sec": messages / elapsed,
         "mean_tick_seconds": elapsed / n_ticks,
         "engine": "fused",
+        # the untimed warm window ran too; misses is the device counter
+        # read above (0, or this function raised)
+        "warm_ticks": window,
+        "misses": misses,
     }
     if tick_durations:
         d = np.asarray(tick_durations)
@@ -466,7 +470,7 @@ async def run_presence_pipelined(engine, n_players: int, n_games: int,
     # the rung ladder (programs + compiles + measured service times) is
     # cached on the engine: bench.py retries this function up to 4 times
     # per budget on one engine, and rebuilding ~6 fused programs per
-    # attempt would be almost all compile wall time on tunneled rigs
+    # attempt would be almost all compile wall time on a slow rig
     cache = getattr(engine, "_pipelined_rung_cache", None)
     if cache is not None and cache["key"] == (n_players, n_games, seed):
         rungs, service = cache["rungs"], cache["service"]

@@ -77,6 +77,36 @@ def test_fold_matches_numpy_replay():
     assert slots[slot] == valid.sum()
 
 
+def test_flush_defers_under_trace_and_folds_after():
+    """A flush reached inside an active jit trace stores no tracer: the
+    fold stays buffered and lands on the next concrete flush."""
+    import jax
+    import jax.numpy as jnp
+
+    eng = _engine()
+    att = eng.attribution
+    arena = eng.arena_for("PresenceGrain")
+    arena.resolve_rows(np.arange(8, dtype=np.int64))
+    rows = np.asarray([0, 3, 3, 7], np.int32)
+    att.record_group(arena, "PresenceGrain", "heartbeat",
+                     jnp.asarray(rows), jnp.ones(4, bool))
+
+    def traced(x):
+        att.flush_folds()
+        return x + 1
+
+    assert int(jax.jit(traced)(1)) == 2
+    assert len(att._pending) == 1
+    counts = att.counts_for("PresenceGrain")
+    assert not isinstance(counts, jax.core.Tracer)
+    assert int(np.asarray(counts).sum()) == 0
+    att.flush_folds()
+    assert att._pending == []
+    np.testing.assert_array_equal(
+        np.asarray(att.counts_for("PresenceGrain")),
+        np.bincount(rows, minlength=arena.capacity))
+
+
 def test_topk_matches_host_oracle_on_zipf():
     """The tentpole contract at test scale: device HotSet == host
     bincount oracle on a skewed workload (the bench tier re-asserts at

@@ -779,7 +779,7 @@ def test_note_tick_on_complete_stamps_in_executor(run):
 
 def test_pin_copy_compile_is_cause_attributed(run):
     """The copy-before-donate pin's jit compile is visible to the churn
-    taxonomy like every other compile site: the first donated chain
+    cause list like every other compile site: the first donated chain
     records a cause-coded event (cache-size delta — cache hits record
     nothing)."""
 

@@ -363,8 +363,8 @@ def test_tcp_client_wide_key_batch_edge_throughput(run):
 
                 async def rate_of(fn):
                     # best of 2: each timed window carries 1M messages
-                    # (well above the tunneled rig's ~100ms completion-
-                    # observation floor) and a single rig hiccup cannot
+                    # (well above a ~100ms completion-observation
+                    # floor) and a single rig hiccup cannot
                     # fail the comparison
                     best = 0.0
                     for _ in range(2):

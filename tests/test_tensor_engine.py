@@ -126,6 +126,32 @@ def test_presence_emit_chain(run):
     run(main())
 
 
+def test_cold_device_keys_emit_once(run):
+    """Heartbeats to unseen players by DEVICE keys miss optimistic
+    resolution and redeliver after activation; the game update each
+    emits must land once, not once per delivery attempt."""
+
+    async def main():
+        engine = TensorEngine()
+        n, n_games = 512, 8
+        keys = np.arange(n, dtype=np.int32)
+        games = keys % n_games
+        engine.send_batch("PresenceGrain", "heartbeat", jnp.asarray(keys),
+                          {"game": jnp.asarray(games),
+                           "score": jnp.ones(n, jnp.float32),
+                           "tick": jnp.ones(n, jnp.int32)})
+        await engine.flush()
+        assert engine.activation_passes >= 1
+        game = engine.arena_for("GameGrain")
+        rows, found = game.lookup_rows(np.arange(n_games, dtype=np.int64))
+        assert found.all()
+        np.testing.assert_array_equal(
+            np.asarray(game.state["updates"])[rows],
+            np.bincount(games, minlength=n_games))
+
+    run(main())
+
+
 def test_proxy_call_routes_to_engine(run):
     """Vector grains remain callable through normal grain references."""
 
