@@ -1161,7 +1161,7 @@ async def _collection_scenario(n_grains: int, hot: int, budget_s: float,
             engine.run_tick()
             if collect_stalls is not None:
                 collect_stalls.append(
-                    engine.last_tick_stages.get("collect", 0.0))
+                    engine.profiler.tick_stages.get("collect", 0.0))
         await engine.flush()
         return time.perf_counter() - t0
 

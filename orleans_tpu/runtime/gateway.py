@@ -24,7 +24,8 @@ import asyncio
 import dataclasses
 import struct
 import time
-from typing import Any, Callable, Dict, Optional
+from collections import defaultdict
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from orleans_tpu import codec as codec_mod
 from orleans_tpu import spans as _spans
@@ -237,9 +238,18 @@ async def read_gateway_frame_any(reader: asyncio.StreamReader) -> Any:
     """Read one gateway frame of either flavor: token-stream payloads
     decode through the general codec, rpc fast-path payloads through
     the fixed-header decoder (returns :class:`codec.RpcFrame`)."""
+    return decode_gateway_frame(*await read_gateway_payload(reader))
+
+
+async def read_gateway_payload(reader: asyncio.StreamReader
+                               ) -> Tuple[int, bytes]:
+    """One frame's (magic, payload bytes), not yet decoded."""
     header = await reader.readexactly(8)
     magic, length = struct.unpack("<II", header)
-    payload = await reader.readexactly(length)
+    return magic, await reader.readexactly(length)
+
+
+def decode_gateway_frame(magic: int, payload: bytes) -> Any:
     if magic == GATEWAY_MAGIC:
         return codec.deserialize(payload)
     if magic == GATEWAY_RPC_MAGIC:
@@ -316,19 +326,26 @@ def _rpc_args_shared_safe(args) -> bool:
     return True
 
 
-async def _rpc_reply(writer: asyncio.StreamWriter, batch_id: int,
+async def _rpc_reply(acceptor: "GatewayAcceptor",
+                     writer: asyncio.StreamWriter, batch_id: int,
                      futures: list) -> None:
     """Resolve one calls-frame's futures into ONE results frame: status
     column + values (collapsed to a single shared value when the whole
     window answered identically — the steady-state helloworld shape)."""
+    results = await asyncio.gather(*futures, return_exceptions=True)
+    if writer.is_closing():
+        return
+    with acceptor.stage("reply"):
+        _write_rpc_results(writer, batch_id, results)
+
+
+def _write_rpc_results(writer: asyncio.StreamWriter, batch_id: int,
+                       results: list) -> None:
     import numpy as np
 
     from orleans_tpu.runtime.messaging import RejectionType
     from orleans_tpu.runtime.runtime_client import RejectionError
 
-    results = await asyncio.gather(*futures, return_exceptions=True)
-    if writer.is_closing():
-        return
     n = len(results)
     statuses = np.zeros(n, dtype=np.uint8)
     clean = True
@@ -380,14 +397,38 @@ def _with_ttl(msg: Message) -> Message:
 
 class GatewayAcceptor:
     """Dedicated client-facing listener on a gateway silo
-    (reference: ProxyGatewayEndpoint + GatewayAcceptor.cs:32)."""
+    (reference: ProxyGatewayEndpoint + GatewayAcceptor.cs:32).
+
+    The loop thread's own work per frame runs in three stages
+    (``orleans.gateway.decode``: the payload's codec decode, not the
+    socket reads; ``.submit``: the hand-off into the engine, the
+    coalescer or the dispatcher; ``.reply``: encoding and writing the
+    reply frame), counted in ``stage_seconds`` beside ``frames``, the
+    frames decoded: the front door's time per frame."""
+
+    STAGES = {k: "orleans.gateway." + k for k in ("decode", "submit",
+                                                  "reply")}
 
     def __init__(self, silo, host: str = "127.0.0.1", port: int = 0) -> None:
+        # imported here: the profiler imports jax, and clients import
+        # this module's frame functions without it
+        from orleans_tpu.tensor.profiler import Stage
+
         self.silo = silo
         self.host = host
         self.port = port
         self._server: Optional[asyncio.AbstractServer] = None
         self._conns: set = set()
+        self._stage = Stage
+        self.frames = 0
+        self.stage_seconds: Dict[str, float] = defaultdict(float)
+
+    def stage(self, key: str):
+        return self._stage(self.STAGES[key], self.stage_seconds, key)
+
+    def snapshot(self) -> Dict[str, Any]:
+        return {"frames": self.frames,
+                "stage_seconds": dict(self.stage_seconds)}
 
     async def start(self) -> None:
         self._server = await asyncio.start_server(self._on_conn, self.host,
@@ -412,10 +453,18 @@ class GatewayAcceptor:
         batched completion.  TTLs rebase PER CALL on this host's clock
         — the frame-level rebase bug class the regression test in
         tests/test_rpc.py pins."""
-        from orleans_tpu.runtime.rpc import _Call
-
         if frame.kind != codec_mod.RPC_KIND_CALLS:
             raise ValueError("client sent a results frame")
+        with self.stage("submit"):
+            self._submit_rpc_calls(gateway, writer, client_id,
+                                   rpc_bindings, frame)
+
+    def _submit_rpc_calls(self, gateway: "Gateway",
+                          writer: asyncio.StreamWriter, client_id: GrainId,
+                          rpc_bindings: Dict[int, Optional["_RpcBinding"]],
+                          frame) -> None:
+        from orleans_tpu.runtime.rpc import _Call
+
         loop = asyncio.get_running_loop()
         want = frame.batch_id != 0 and not frame.one_way
         binding = rpc_bindings.get(frame.rpc_id)
@@ -467,7 +516,7 @@ class GatewayAcceptor:
         gateway.submit_calls(calls)
         if want:
             task = loop.create_task(
-                _rpc_reply(writer, frame.batch_id, futures))
+                _rpc_reply(self, writer, frame.batch_id, futures))
             task.add_done_callback(lambda t: t.cancelled()
                                    or t.exception())
 
@@ -496,10 +545,14 @@ class GatewayAcceptor:
                                          "silo": str(self.silo.address)})
 
             while True:
-                frame = await read_gateway_frame_any(reader)
+                magic, payload = await read_gateway_payload(reader)
+                with self.stage("decode"):
+                    frame = decode_gateway_frame(magic, payload)
+                self.frames += 1
                 if isinstance(frame, Message):
-                    gateway.submit(_rebase_expiration_inbound(frame),
-                                   already_wired=True)
+                    with self.stage("submit"):
+                        gateway.submit(_rebase_expiration_inbound(frame),
+                                       already_wired=True)
                 elif isinstance(frame, RpcFrame):
                     self._handle_rpc_calls(gateway, writer, client_id,
                                            rpc_bindings, frame)
@@ -531,6 +584,11 @@ class GatewayAcceptor:
                                    _id=batch_id) -> None:
                             if writer.is_closing():
                                 return
+                            with self.stage("reply"):
+                                _write_batch_result(f, _id)
+
+                        def _write_batch_result(f: "asyncio.Future",
+                                                _id) -> None:
                             if f.exception() is not None:
                                 write_gateway_frame(writer, {
                                     "op": "batch_result", "batch_id": _id,
@@ -541,11 +599,12 @@ class GatewayAcceptor:
                                     "result": f.result()})
 
                         try:
-                            fut = gateway.submit_batch(
-                                frame["type"], frame["method"],
-                                frame["keys"], frame["args"],
-                                want_results=frame.get("want_results",
-                                                       False))
+                            with self.stage("submit"):
+                                fut = gateway.submit_batch(
+                                    frame["type"], frame["method"],
+                                    frame["keys"], frame["args"],
+                                    want_results=frame.get("want_results",
+                                                           False))
                         except Exception as exc:  # noqa: BLE001
                             if batch_id is not None:
                                 write_gateway_frame(writer, {

@@ -899,9 +899,18 @@ class Silo:
 
         ``mirror=True`` additionally fans the same (name, value) pairs
         out to the process TelemetryManager's metric consumers — the
-        legacy ad-hoc surface, preserved for existing sinks/tests."""
+        legacy ad-hoc surface, preserved for existing sinks/tests.
+
+        Runs as the ``orleans.metrics.collect`` stage: the ledger and
+        attribution reads it triggers block the loop thread."""
         if not self.config.metrics.enabled:
             return {}
+        from orleans_tpu.tensor.profiler import Stage
+        with Stage("orleans.metrics.collect"):
+            return self._collect_metrics(mirror, force_ledger)
+
+    def _collect_metrics(self, mirror: bool,
+                         force_ledger: bool) -> Dict[str, Any]:
         from orleans_tpu import telemetry
         reg = self.metrics_registry
         mgr = telemetry.default_manager

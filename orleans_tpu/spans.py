@@ -36,8 +36,10 @@ is the causal thread between them, the Dapper model (Sigelman et al.,
 
 Everything here is host-path bookkeeping: plain dataclasses and deques,
 zero device work.  With ``TracingConfig.enabled=False`` every entry
-point returns before allocating anything (bench.py's ``trace_overhead``
-section proves the cost envelope).
+point returns before allocating anything.  These spans live on the
+host clock only; the spans that reach the device trace, on its clock,
+are the profiler's stages (``tensor/profiler.Stage``), and a tick
+span's ``phases`` are folded from those stages' totals.
 """
 
 from __future__ import annotations

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from orleans_tpu.hashing import stable_hash_u64
+from orleans_tpu.tensor.profiler import host_read
 from orleans_tpu.tensor.vector_grain import StateField, VectorGrainInfo
 
 
@@ -334,8 +335,8 @@ class GrainArena:
 
     def effective_last_use(self) -> np.ndarray:
         """Merge the host and device use clocks (collection-time only)."""
-        return np.maximum(self.last_use_tick,
-                          np.asarray(self.last_use_dev, dtype=np.int64))
+        return np.maximum(self.last_use_tick, np.asarray(
+            host_read("arena", self.last_use_dev), dtype=np.int64))
 
     def _init_state_columns(self, capacity: int) -> None:
         self.state = {name: self._make_column(f, capacity)
@@ -708,8 +709,8 @@ class GrainArena:
         data-dependent row counts reuse O(log n) compiled gathers."""
         n = len(rows)
         idx = jnp.asarray(_pow2_pad(rows, 0))
-        host = jax.device_get({name: col[idx]
-                               for name, col in self.state.items()})
+        host = host_read("arena", {name: col[idx]
+                                   for name, col in self.state.items()})
         return {name: col[:n] for name, col in host.items()}
 
     def shard_occupancy(self) -> np.ndarray:
@@ -765,8 +766,8 @@ class GrainArena:
         rows = rows[self._key_of_row[rows] >= 0]
         if len(rows) == 0:
             return 0
-        dev = np.asarray(self.last_use_dev[
-            jnp.asarray(_pow2_pad(rows, 0))])[:len(rows)]
+        dev = np.asarray(host_read("arena", self.last_use_dev[
+            jnp.asarray(_pow2_pad(rows, 0))]))[:len(rows)]
         idle = np.maximum(self.last_use_tick[rows],
                           dev.astype(np.int64)) < older_than_tick
         return self._deactivate_rows(rows[idle], write_back)
@@ -1192,8 +1193,8 @@ class GrainArena:
             self.state[name] = self.state[name].at[dst].set(
                 jnp.asarray(val))
         # merge the use clocks: the primary inherits the hottest replica
-        dev = np.asarray(self.last_use_dev[
-            jnp.asarray(_pow2_pad(rws, 0))])[:len(rws)]
+        dev = np.asarray(host_read("arena", self.last_use_dev[
+            jnp.asarray(_pow2_pad(rws, 0))]))[:len(rws)]
         self.last_use_dev = self.last_use_dev.at[dst].max(
             jnp.int32(int(dev.max())))
         self.last_use_tick[prow] = int(self.last_use_tick[rws].max())

@@ -88,6 +88,7 @@ import numpy as np
 
 from orleans_tpu.tensor.arena import _pow2_pad
 from orleans_tpu.tensor.ledger import MAX_SLOTS, SlotRegistry
+from orleans_tpu.tensor.profiler import host_read
 
 #: multiply-shift seed per sketch depth (odd constants; depth is capped
 #: by the seed count — 8 depths drive the failure probability to e^-8)
@@ -684,8 +685,8 @@ class WorkloadAttribution:
         if col is None or len(victims) == 0:
             return
         idx = _pow2_pad(victims.astype(np.int32), 0)
-        vals = np.asarray(_gather_counts(col, jnp.asarray(idx)))[
-            :len(victims)]
+        vals = np.asarray(host_read("attribution_evict", _gather_counts(
+            col, jnp.asarray(idx))))[:len(victims)]
         retired = self._retired.setdefault(arena.info.name, {})
         nz = vals > 0
         for k, v in zip(keys[nz].tolist(), vals[nz].tolist()):
@@ -794,7 +795,7 @@ class WorkloadAttribution:
             pend["__slots__"] = self._slot_arr()
         if self._stale is not None:
             pend["__stale__"] = self._stale
-        fetched = jax.device_get(pend) if pend else {}
+        fetched = host_read("attribution", pend) if pend else {}
         if pend:
             self.d2h_fetches += 1
         stale_now = int(fetched.get("__stale__", self._last_stale))
@@ -845,9 +846,10 @@ class WorkloadAttribution:
                     if found.any():
                         idx = _pow2_pad(
                             erows[found].astype(np.int32), 0)
-                        live_counts[found] = np.asarray(_gather_counts(
-                            self._counts[type_name],
-                            jnp.asarray(idx)))[:int(found.sum())]
+                        live_counts[found] = np.asarray(host_read(
+                            "attribution", _gather_counts(
+                                self._counts[type_name],
+                                jnp.asarray(idx))))[:int(found.sum())]
                         self.d2h_fetches += 1
                     for (k, c), lc in zip(extra, live_counts.tolist()):
                         cand[k] = {"msgs": int(c) + int(lc),

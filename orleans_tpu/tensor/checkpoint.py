@@ -75,6 +75,7 @@ import numpy as np
 from orleans_tpu.tensor.arena import _pow2_pad
 from orleans_tpu.tensor.attribution import pow2ceil
 from orleans_tpu.tensor.persistence import fsync_write
+from orleans_tpu.tensor.profiler import host_read
 
 
 @jax.jit
@@ -478,8 +479,8 @@ class DeviceJournal:
             if not s.entries:
                 continue
             arrays: Dict[str, np.ndarray] = {}
-            host_dev = jax.device_get(s.dev_arrays) if s.dev_arrays \
-                else []
+            host_dev = host_read("checkpoint", s.dev_arrays) \
+                if s.dev_arrays else []
             for i, a in enumerate(host_dev):
                 arrays[f"d{i}"] = np.asarray(a)
             for i, a in enumerate(s.host_arrays):
@@ -974,7 +975,7 @@ class CheckpointPlane:
             # fixed-size pow2 pad: one compiled gather per (arena
             # layout, chunk) instead of per data-dependent length
             idx = jnp.asarray(_pow2_pad(rows.astype(np.int32), 0))
-            host = jax.device_get(_gather_tree(a["pin"], idx))
+            host = host_read("checkpoint", _gather_tree(a["pin"], idx))
             arrays = {k: np.asarray(v)[:len(rows)]
                       for k, v in host.items()}
             arrays["__rows"] = rows

@@ -65,6 +65,7 @@ from orleans_tpu.tensor.profiler import (
     CAUSE_SHAPE_CHANGE,
     CompileTracker,
     TickPhaseProfiler,
+    host_read,
 )
 from orleans_tpu.tensor.vector_grain import (
     KEY_SENTINEL,
@@ -732,15 +733,11 @@ class TensorEngine:
         self.tick_durations: deque = deque(maxlen=self.config.latency_window)
         self._adaptive_interval = self.config.tick_interval
         # per-stage host wall time (the StageAnalysis analog, reference:
-        # src/Orleans/Statistics/StageAnalysis.cs:81): cumulative seconds
-        # per pipeline stage plus the last tick's breakdown, so a slow tick
-        # can name its slow stage.  Device work is async-dispatched; a
-        # stage's time is its host-side cost plus any device sync its data
-        # dependencies force.
-        self.stage_seconds: Dict[str, float] = defaultdict(float)
-        self.last_tick_stages: Dict[str, float] = {}
-        self._tick_stages: Dict[str, float] = defaultdict(float)
-        self._in_tick = False
+        # src/Orleans/Statistics/StageAnalysis.cs:81) lives on the
+        # profiler's stages (profiler.stage_seconds / tick_stages), so a
+        # slow tick can name its slow stage.  Device work is
+        # async-dispatched; a stage's time is its host-side cost plus any
+        # device sync its data dependencies force.
 
         self._step_cache: Dict[Tuple[str, str, int], Callable] = {}
         # compile-churn attribution (tensor/profiler.py): step-call input
@@ -1622,7 +1619,7 @@ class TensorEngine:
             # the tick was consumed into (or ran as part of) a fused
             # window — counters/latency are accounted by the window run
             return
-        t0 = time.perf_counter()
+        prof = self.profiler
         rec = self._span_recorder()
         if rec is not None:
             self._tick_traces = []
@@ -1630,79 +1627,10 @@ class TensorEngine:
             span_msgs0 = self.messages_processed
             span_compiles0 = self.compile_count()
             span_start = time.monotonic()
-        self.tick_number += 1
-        self.ticks_run += 1
-        stages = self._tick_stages = defaultdict(float)
-        self._in_tick = True
-        cfg = self.config
-        if cfg.collection_idle_ticks and cfg.collection_every_ticks > 0:
-            # incremental collection: the cadence tick SELECTS victims
-            # (device compare, mask-only transfer); every tick thereafter
-            # drains one pause-budgeted slice until the sweep finishes.
-            # The tick never stalls past the budget + one chunk.
-            if (not self.collector.active()
-                    and self.tick_number % cfg.collection_every_ticks == 0):
-                self.collector.start_sweep(
-                    self.tick_number - cfg.collection_idle_ticks)
-            if self.collector.active():
-                self.collector.run_slice(cfg.collection_pause_budget_s,
-                                         cfg.collection_chunk_rows)
-                stages["collect"] += time.perf_counter() - t0
-        if cfg.timers_plane and self.timers.armed_total:
-            # harvest due timers BEFORE the rounds loop so fired
-            # batches deliver within this same tick
-            dt_tm = self.timers.advance_to(self.tick_number)
-            if dt_tm:
-                stages["timers"] += dt_tm
-        if len(self._pending_checks) + len(self._exchange_checks) \
-                + len(self._fanout_checks) >= self.config.miss_check_cap:
-            # bound device memory pinned by parked optimistic checks
-            # (exchange and fan-out overflow checks pin their batch's
-            # args the same way, so they count against the same cap)
-            self._drain_checks()
-        rounds = 0
-        while rounds < self.config.max_rounds_per_tick:
-            pending = {k: v for k, v in self.queues.items() if v}
-            if not pending:
-                break
-            self.queues = defaultdict(list)
-            if self._exchange_live() and self.config.exchange_overlap \
-                    and self.router is None and self.exchange.engaged():
-                self._pre_exchange_round(pending, stages)
-            for (type_name, method), batches in pending.items():
-                tf = time.perf_counter()
-                if self.router is not None:
-                    # ownership + handoff fence BEFORE fan-out: shipped
-                    # and fence-deferred batches must not expand their
-                    # subscriber deliveries locally this tick
-                    batches = self._route_group(type_name, method, batches)
-                    if not batches:
-                        stages["fanout"] += time.perf_counter() - tf
-                        continue
-                self._run_fanout(type_name, method, batches)
-                batches = self._run_stream_routes_pre(type_name, method,
-                                                      batches)
-                stages["fanout"] += time.perf_counter() - tf
-                self._run_group(type_name, method, batches)
-            rounds += 1
-            self.rounds_run += 1
-        if self._fence_deferred:
-            for qkey, b in self._fence_deferred:
-                self.queues[qkey].append(b)
-            self._fence_deferred = []
-        t_cp = self.maybe_periodic_checkpoint()
-        if t_cp:
-            stages["checkpoint"] += t_cp
-        # durable state plane: start/advance a due snapshot drain under
-        # its pause budget + keep the journal segment cadence
-        t_ck = self.checkpointer.on_tick()
-        if t_ck:
-            stages["checkpoint"] += t_ck
-        dt = time.perf_counter() - t0
-        self._in_tick = False
-        for k, v in stages.items():
-            self.stage_seconds[k] += v
-        self.last_tick_stages = dict(stages)
+        with prof.tick() as tick:
+            rounds, timers_fired = self._tick_body()
+        dt = tick.seconds
+        stages = prof.tick_stages
         self.tick_seconds += dt
         self.tick_durations.append(dt)
         # tick-phase profiler (tensor/profiler.py): fold the stage
@@ -1726,7 +1654,7 @@ class TensorEngine:
             rec.plane_span("streams", "fan-out tick",
                            duration=stages["fanout"],
                            tick=self.tick_number, rounds=rounds)
-        if rec is not None and stages.get("timers"):
+        if rec is not None and timers_fired:
             rec.plane_span("timers", "advance",
                            duration=stages["timers"],
                            tick=self.tick_number,
@@ -1747,6 +1675,76 @@ class TensorEngine:
         # even if the profiler was live-disabled mid-capture
         self.profiler.tick_done()
         self._adapt(dt)
+
+    def _tick_body(self) -> Tuple[int, float]:
+        """One tick's work inside its ``orleans.tick`` stage; returns
+        the rounds run and the seconds the timer plane reported."""
+        prof = self.profiler
+        self.tick_number += 1
+        self.ticks_run += 1
+        cfg = self.config
+        if cfg.collection_idle_ticks and cfg.collection_every_ticks > 0:
+            # incremental collection: the cadence tick SELECTS victims
+            # (device compare, mask-only transfer); every tick thereafter
+            # drains one pause-budgeted slice until the sweep finishes.
+            # The tick never stalls past the budget + one chunk.
+            with prof.stage("collect"):
+                if (not self.collector.active()
+                        and self.tick_number
+                        % cfg.collection_every_ticks == 0):
+                    self.collector.start_sweep(
+                        self.tick_number - cfg.collection_idle_ticks)
+                if self.collector.active():
+                    self.collector.run_slice(cfg.collection_pause_budget_s,
+                                             cfg.collection_chunk_rows)
+        timers_fired = 0.0
+        if cfg.timers_plane and self.timers.armed_total:
+            # harvest due timers BEFORE the rounds loop so fired
+            # batches deliver within this same tick
+            with prof.stage("timers"):
+                timers_fired = self.timers.advance_to(self.tick_number)
+        if len(self._pending_checks) + len(self._exchange_checks) \
+                + len(self._fanout_checks) >= self.config.miss_check_cap:
+            # bound device memory pinned by parked optimistic checks
+            # (exchange and fan-out overflow checks pin their batch's
+            # args the same way, so they count against the same cap)
+            self._drain_checks()
+        rounds = 0
+        while rounds < self.config.max_rounds_per_tick:
+            pending = {k: v for k, v in self.queues.items() if v}
+            if not pending:
+                break
+            self.queues = defaultdict(list)
+            if self._exchange_live() and self.config.exchange_overlap \
+                    and self.router is None and self.exchange.engaged():
+                self._pre_exchange_round(pending)
+            for (type_name, method), batches in pending.items():
+                with prof.stage("fanout"):
+                    if self.router is not None:
+                        # ownership + handoff fence BEFORE fan-out:
+                        # shipped and fence-deferred batches must not
+                        # expand their subscriber deliveries locally
+                        batches = self._route_group(type_name, method,
+                                                    batches)
+                    local = bool(batches)
+                    if local:
+                        self._run_fanout(type_name, method, batches)
+                        batches = self._run_stream_routes_pre(
+                            type_name, method, batches)
+                if local:
+                    self._run_group(type_name, method, batches)
+            rounds += 1
+            self.rounds_run += 1
+        if self._fence_deferred:
+            for qkey, b in self._fence_deferred:
+                self.queues[qkey].append(b)
+            self._fence_deferred = []
+        with prof.stage("checkpoint"):
+            self.maybe_periodic_checkpoint()
+            # durable state plane: start/advance a due snapshot drain
+            # under its pause budget + keep the journal segment cadence
+            self.checkpointer.on_tick()
+        return rounds, timers_fired
 
     def tick_interval(self) -> float:
         """Seconds to accumulate messages before the next tick."""
@@ -1843,7 +1841,12 @@ class TensorEngine:
         if not self._pending_checks and not self._exchange_checks \
                 and not self._fanout_checks:
             return False
-        t0 = time.perf_counter()
+        # within a tick the drain is part of that tick's breakdown;
+        # between ticks it accrues to the cumulative totals directly
+        with self.profiler.stage("miss_checks"):
+            return self._drain_parked_checks()
+
+    def _drain_parked_checks(self) -> bool:
         checks = self._pending_checks
         self._pending_checks = []
         requeued = self._drain_exchange_checks()
@@ -1854,13 +1857,14 @@ class TensorEngine:
         # pads to the next power of two so the varargs jit compiles
         # O(log cap) programs, not one per distinct count
         if len(checks) == 1:
-            counts = [int(checks[0].miss_count)]
+            counts = [int(host_read("miss_checks", checks[0].miss_count))]
         else:
             n = len(checks)
             padded = 1 << (n - 1).bit_length()
             xs = [c.miss_count for c in checks] \
                 + [np.int32(0)] * (padded - n)
-            counts = np.asarray(_stack_counts(*xs))[:n].tolist()
+            counts = np.asarray(host_read(
+                "miss_checks", _stack_counts(*xs)))[:n].tolist()
         for c, cnt in zip(checks, counts):
             if cnt == 0:
                 continue
@@ -1889,7 +1893,7 @@ class TensorEngine:
                 continue
             miss_keys, missing = _miss_keys_kernel(c.keys, c.rows, c.valid,
                                                    miss_buf=MISS_BUF)
-            mk = np.asarray(miss_keys)
+            mk = np.asarray(host_read("miss_checks", miss_keys))
             mk = mk[mk != KEY_SENTINEL].astype(np.int64)
             if self.router is not None and len(mk):
                 # single-activation across silos: a miss key owned by a
@@ -1943,11 +1947,6 @@ class TensorEngine:
                 args=c.args, keys_dev=c.keys, mask=missing,
                 inject_tick=c.inject_tick))
             requeued = True
-        # within a tick the drain is part of that tick's breakdown (folded
-        # into stage_seconds at tick end); between ticks it accrues to the
-        # cumulative totals directly
-        sink = self._tick_stages if self._in_tick else self.stage_seconds
-        sink["miss_checks"] += time.perf_counter() - t0
         return requeued
 
     def _drain_fanout_checks(self) -> bool:
@@ -1965,13 +1964,14 @@ class TensorEngine:
         checks = self._fanout_checks
         self._fanout_checks = []
         if len(checks) == 1:
-            counts = [int(checks[0].count)]
+            counts = [int(host_read("fanout_drops", checks[0].count))]
         else:
             n = len(checks)
             padded = 1 << (n - 1).bit_length()
             xs = [c.count for c in checks] \
                 + [np.int32(0)] * (padded - n)
-            counts = np.asarray(_stack_counts(*xs))[:n].tolist()
+            counts = np.asarray(host_read(
+                "fanout_drops", _stack_counts(*xs)))[:n].tolist()
         requeued = False
         for c, cnt in zip(checks, counts):
             exp = c.expander
@@ -1992,7 +1992,7 @@ class TensorEngine:
             requeued = True
         return requeued
 
-    def _pre_exchange_round(self, pending, stages) -> None:
+    def _pre_exchange_round(self, pending) -> None:
         """Exchange OVERLAP, unfused path (tensor/exchange.py): at round
         start, dispatch the cross-shard exchange for every queued batch
         whose resolution is ALREADY CACHED (injector fast path) — the
@@ -2004,8 +2004,7 @@ class TensorEngine:
         recomputes inline.  Clustered silos skip this (a batch may ship
         to another silo before it runs — the pre-dispatch would be
         wasted device work)."""
-        t0 = time.perf_counter()
-        did = False
+        exchange = self.profiler.stage("exchange").open()
         for (type_name, method), batches in pending.items():
             if len(batches) != 1:
                 continue
@@ -2031,9 +2030,7 @@ class TensorEngine:
             b.pre_exchange = (r2, a2, m2, dropped, stats,
                               arena.generation, arena.eviction_epoch,
                               b.rows, time.perf_counter(), run_cost)
-            did = True
-        if did:
-            stages["exchange"] += time.perf_counter() - t0
+        exchange.close()
 
     def _drain_exchange_checks(self) -> bool:
         """Quiescence half of the cross-shard exchange: fold the parked
@@ -2046,14 +2043,16 @@ class TensorEngine:
         checks = self._exchange_checks
         self._exchange_checks = []
         if len(checks) == 1:
-            stats = np.asarray(checks[0].stats)[None, :]
+            stats = np.asarray(host_read("exchange_stats",
+                                         checks[0].stats))[None, :]
         else:
             n = len(checks)
             padded = 1 << (n - 1).bit_length()
             width = int(checks[0].stats.shape[0])
             xs = [c.stats for c in checks] \
                 + [np.zeros(width, np.int32)] * (padded - n)
-            stats = np.asarray(_stack_counts(*xs))[:n]
+            stats = np.asarray(host_read("exchange_stats",
+                                         _stack_counts(*xs)))[:n]
         xch = self.exchange
         requeued = False
         for c, row in zip(checks, stats):
@@ -2259,8 +2258,8 @@ class TensorEngine:
                 return
         info = vector_type(type_name)
         arena = self.arena_for(type_name)
-        stages = self._tick_stages
-        t_res = time.perf_counter()
+        prof = self.profiler
+        resolve = prof.stage("resolve").open()
         if self._span_recorder() is not None:
             # tick-span accounting BEFORE coalescing (the merge keeps the
             # payloads but not the per-batch trace contexts)
@@ -2387,8 +2386,7 @@ class TensorEngine:
 
         self.messages_processed += m_total
         want_results = any(b.future is not None for b in batches)
-        t_x = time.perf_counter()
-        stages["resolve"] += t_x - t_res
+        resolve.close()
 
         if (self._exchange_live() and not self.exchange.engaged()
                 and arena.sharding is not None
@@ -2426,6 +2424,7 @@ class TensorEngine:
             # on device, parked like a miss-check; messages_processed
             # already counted the LOGICAL lanes above (the exchanged
             # width is a padded transport shape, not traffic).
+            xst = prof.stage("exchange").open()
             keys_cat = batches[0].keys_dev if len(batches) == 1 \
                 else jnp.concatenate([b.keys_dev for b in batches])
             base = mask if mask is not None \
@@ -2470,8 +2469,8 @@ class TensorEngine:
                 ledger.record_rows(type_name, method,
                                    self.tick_number - inj, rows, mask)
             exchanged = True
-            stages["exchange"] += time.perf_counter() - t_x
-        t_apply = time.perf_counter()
+            xst.close()
+        apply = prof.stage("apply").open()
 
         step = self._get_step(info, method)
         if not self._steps_donated:
@@ -2541,14 +2540,12 @@ class TensorEngine:
             # cross to the host, so record their traffic on the device-side
             # use clock — otherwise collection would evict hot rows
             arena.touch_rows_dev(rows, self.tick_number)
-        t_route = time.perf_counter()
-        stages["apply"] += t_route - t_apply
-        self._route_emits(emits)
-        stages["route"] += time.perf_counter() - t_route
+        apply.close()
+        with prof.stage("route"):
+            self._route_emits(emits)
         if want_results:
-            t_dr = time.perf_counter()
-            self._deliver_results(batches, results)
-            stages["results"] += time.perf_counter() - t_dr
+            with prof.stage("results"):
+                self._deliver_results(batches, results)
 
     def _run_segments_batch(self, type_name: str, method: str,
                             b: PendingBatch) -> None:
@@ -2570,8 +2567,8 @@ class TensorEngine:
                 inject_tick=b.inject_tick))
             return
         info = vector_type(type_name)
-        stages = self._tick_stages
-        t0 = time.perf_counter()
+        prof = self.profiler
+        resolve = prof.stage("resolve").open()
         m = len(b)
         if self._span_recorder() is not None:
             if b.trace is not None:
@@ -2591,8 +2588,8 @@ class TensorEngine:
                                           b.rows, b.mask,
                                           ident=b.keys_dev)
         self.messages_processed += m
-        t_apply = time.perf_counter()
-        stages["resolve"] += t_apply - t0
+        resolve.close()
+        apply = prof.stage("apply").open()
         step = self._get_step(info, method)
         if not self._steps_donated:
             self.donation_fallbacks += 1
@@ -2616,10 +2613,9 @@ class TensorEngine:
         # holding edges (the offsets know them) — never a lane-sized
         # scatter-max on this path
         arena.touch_rows_dense(b.segments, self.tick_number)
-        t_route = time.perf_counter()
-        stages["apply"] += t_route - t_apply
-        self._route_emits(emits)
-        stages["route"] += time.perf_counter() - t_route
+        apply.close()
+        with prof.stage("route"):
+            self._route_emits(emits)
 
     def _deliver_results(self, batches: List[PendingBatch],
                          results: Any) -> None:
@@ -2632,7 +2628,8 @@ class TensorEngine:
                 else:
                     # d2h only here — the caller explicitly asked
                     b.future.set_result(jax.tree_util.tree_map(
-                        lambda x: np.asarray(x[start:start + m]), results))
+                        lambda x: np.asarray(host_read(
+                            "results", x[start:start + m])), results))
             start += m
 
     def _route_emits(self, emits) -> None:
@@ -2806,8 +2803,8 @@ class TensorEngine:
             "msgs_per_sec": (self.messages_processed / self.tick_seconds
                              if self.tick_seconds > 0 else 0.0),
             "activation_passes": self.activation_passes,
-            "stages": dict(self.stage_seconds),
-            "last_tick_stages": dict(self.last_tick_stages),
+            "stages": dict(self.profiler.stage_seconds),
+            "last_tick_stages": dict(self.profiler.tick_stages),
             "tick_latency": self.latency_stats(),
             # continuous pipelined ticking: in-flight window, completion
             # events, overlap credit, donation fallbacks

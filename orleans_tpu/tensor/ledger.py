@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from orleans_tpu.tensor.profiler import host_read
+
 #: fixed slot capacity: 64 distinct (type, method) pairs per engine.
 #: Fixing it keeps the device hist shape constant for the whole engine
 #: lifetime — the accumulate kernel and any fused program baking the
@@ -254,7 +256,7 @@ class DeviceLatencyLedger:
         free (the cached fetch + host mirror answer)."""
         if self._dev_dirty and self._hist is not None:
             self._last_fetch = np.asarray(
-                jax.device_get(self._hist), dtype=np.int64)
+                host_read("ledger", self._hist), dtype=np.int64)
             self._dev_dirty = False
             self.d2h_fetches += 1
         dev = self._last_fetch if self._last_fetch is not None \

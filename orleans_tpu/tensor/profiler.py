@@ -9,12 +9,29 @@ of a guess.  Always-on and cheap, in the spirit of Google-Wide Profiling
 (Ren et al., CACM 2010): cost attribution is a permanent plane, not an
 ad-hoc debugging session.
 
-Three pieces:
+Four pieces:
 
-* **TickPhaseProfiler** — splits every engine tick into five canonical
-  phases (``host`` bookkeeping, ``h2d`` injection/resolve, ``dispatch``
-  kernel dispatch, ``route`` emit/fan-out routing, ``d2h`` write-back)
-  from the engine's per-stage host timers, accumulates per-phase log2
+* **Stage** — the one boundary marker.  Entering a stage adds its
+  seconds to that stage's totals, opens a ``jax.profiler``
+  ``TraceAnnotation`` of its fixed name while a profiler session is
+  active (the harness's ``start_trace`` or ``capture()`` below), so the
+  host spans share the device trace's clock, and records it as the
+  innermost stage open on its thread, which the silo watchdog reads
+  mid-stall.  The spans:
+
+  - ``orleans.tick``: one engine tick (``TensorEngine.run_tick``);
+  - ``orleans.tick.<stage>``: a stage of the tick, its phase per
+    ``STAGE_TO_PHASE`` below;
+  - ``orleans.sync.<site>``: a blocking device read (:func:`host_read`);
+  - ``orleans.gateway.decode``, ``.submit``, ``.reply``: a gateway
+    frame's loop-thread work (``runtime/gateway.GatewayAcceptor``);
+  - ``orleans.metrics.collect``: ``Silo.collect_metrics``.
+
+* **TickPhaseProfiler** — splits every engine tick into the canonical
+  phases (``host`` bookkeeping, ``h2d`` injection/resolve, ``exchange``
+  cross-shard dispatch, ``dispatch`` kernel dispatch, ``route``
+  emit/fan-out routing, ``d2h`` write-back) from its per-stage totals
+  (the ``tick.<stage>`` spans), accumulates per-phase log2
   histograms (the PR 6 bucket scheme, base 1us — mirrored into the
   ``MetricsRegistry`` by ``silo.collect_metrics``) and attaches the
   per-tick breakdown to the batched tick span.  The time not covered by
@@ -45,13 +62,138 @@ from __future__ import annotations
 import math
 import os
 import tempfile
+import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from orleans_tpu.config import ProfilerConfig
+
+# ---------------------------------------------------------------------------
+# the stage primitive
+# ---------------------------------------------------------------------------
+
+_perf_counter = time.perf_counter
+_get_ident = threading.get_ident
+_TraceAnnotation = jax.profiler.TraceAnnotation
+#: True while a profiler session records host annotations
+_tracing = _TraceAnnotation.is_enabled
+
+#: thread ident → the stages open on that thread, outermost first
+_open: Dict[int, List["Stage"]] = {}
+
+
+class Stage:
+    """One named boundary, marked once for every sink: ``with`` it (or
+    ``open()``/``close()`` around a segment that a block cannot hold).
+
+    Closing adds the seconds to ``totals[key]`` (when ``totals`` is
+    given) and leaves them on ``seconds``.  While a profiler session is
+    active the stage is also a ``TraceAnnotation`` of ``name``; with
+    none, the stage costs two clock reads and a list push and pop.
+    Closing a stage first closes any inner stage an exception left
+    open, so the thread's open-stage stack cannot drift."""
+
+    __slots__ = ("name", "totals", "key", "seconds", "_t0", "_ann",
+                 "_stack", "_depth")
+
+    def __init__(self, name: str, totals: Optional[Dict[str, float]] = None,
+                 key: Optional[str] = None) -> None:
+        self.name = name
+        self.totals = totals
+        self.key = name if key is None else key
+        self.seconds = 0.0
+        self._stack: Optional[List[Stage]] = None
+
+    def open(self) -> "Stage":
+        tid = _get_ident()
+        stack = _open.get(tid)
+        if stack is None:
+            stack = _open[tid] = []
+        self._stack = stack
+        self._depth = len(stack)
+        stack.append(self)
+        if _tracing():
+            self._ann = _TraceAnnotation(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = _perf_counter()
+        return self
+
+    def close(self) -> float:
+        stack = self._stack
+        if stack is None:
+            return 0.0  # not open
+        dt = _perf_counter() - self._t0
+        self._stack = None
+        for inner in reversed(stack[self._depth + 1:]):
+            inner.close()
+        del stack[self._depth:]
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.seconds = dt
+        if self.totals is not None:
+            self.totals[self.key] += dt
+        return dt
+
+    __enter__ = open
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+def innermost_stage(thread_id: int) -> Optional[str]:
+    """The name of the innermost stage open on ``thread_id`` (any
+    thread may ask), or None."""
+    stack = _open.get(thread_id)
+    try:
+        return stack[-1].name if stack else None
+    except IndexError:  # popped between the test and the read
+        return None
+
+
+# ---------------------------------------------------------------------------
+# blocking device reads
+# ---------------------------------------------------------------------------
+
+#: every site that blocks the event-loop thread on a device read
+SYNC_SITES = (
+    "miss_checks",       # engine: parked miss-check counts and miss keys
+    "fanout_drops",      # engine: parked fan-out drop counts
+    "exchange_stats",    # engine: parked cross-shard exchange stats
+    "results",           # engine: result delivery to a caller's future
+    "ledger",            # latency ledger snapshot
+    "attribution",       # attribution snapshot and its gathers
+    "attribution_evict",  # attribution: evicted rows' counts retired
+    "arena",             # arena row and use-clock reads
+    "checkpoint",        # journal seals and snapshot chunk drains
+)
+_SYNC_NAMES = {s: "orleans.sync." + s for s in SYNC_SITES}
+#: per site: seconds blocked and reads made, since process start (per
+#: process, like the loop thread every silo in the process shares)
+sync_seconds: Dict[str, float] = defaultdict(float)
+sync_calls: Dict[str, int] = defaultdict(int)
+
+
+def host_read(site: str, x: Any) -> Any:
+    """``jax.device_get(x)`` inside the ``orleans.sync.<site>`` stage:
+    the wait for the device and the copy back, counted per site."""
+    with Stage(_SYNC_NAMES[site], sync_seconds, site):
+        out = jax.device_get(x)
+    sync_calls[site] += 1
+    return out
+
+
+def host_reads() -> Dict[str, Dict[str, float]]:
+    """Seconds and calls of :func:`host_read`, per site."""
+    return {s: {"seconds": sync_seconds[s], "calls": sync_calls[s]}
+            for s in SYNC_SITES if sync_calls[s]}
+
 
 # ---------------------------------------------------------------------------
 # phase model
@@ -62,11 +204,13 @@ from orleans_tpu.config import ProfilerConfig
 #: all_to_all dispatch between resolution and the step kernel.
 PHASES = ("host", "h2d", "exchange", "dispatch", "route", "d2h")
 
-#: engine stage-timer key → canonical phase.  Stages are disjoint
-#: perf_counter segments inside run_tick, so their sum never exceeds the
-#: tick wall time; whatever the stages did not cover is ``host``
-#: bookkeeping (queue plumbing, span accounting, Python overhead).
+#: tick stage (span ``orleans.tick.<stage>``) → canonical phase.  The
+#: stages of one tick are disjoint segments inside run_tick, so their
+#: sum never exceeds the tick wall time; whatever the stages did not
+#: cover is ``host`` bookkeeping (queue plumbing, span accounting,
+#: Python overhead).
 STAGE_TO_PHASE: Dict[str, str] = {
+    "timers": "host",        # due-timer harvest
     "fanout": "host",        # subscription expansion bookkeeping
     "miss_checks": "host",   # optimistic-resolution drain
     "resolve": "h2d",        # coalesce + pad + destination resolution
@@ -77,6 +221,7 @@ STAGE_TO_PHASE: Dict[str, str] = {
     "collect": "d2h",        # eviction write-back slice
     "checkpoint": "d2h",     # periodic arena write-back
 }
+_TICK_NAMES = {k: "orleans.tick." + k for k in STAGE_TO_PHASE}
 
 
 def _bucket(value: float, base: float, n: int) -> int:
@@ -90,15 +235,22 @@ def _bucket(value: float, base: float, n: int) -> int:
 class TickPhaseProfiler:
     """Per-engine phase accounting + triggered deep capture.
 
-    All accounting is host-side numpy scalar arithmetic (a handful of
-    adds per tick); the <5% live-toggle A/B in ``bench.py --workload
-    profile`` pins the envelope.  Disabled, ``observe_tick`` is never
-    called (the engine gates on ``enabled``)."""
+    All accounting is host-side scalar arithmetic: a stage costs two
+    clock reads, a dict add and a list push and pop, and the phase fold
+    a handful of adds per tick.  Disabled, ``observe_tick`` is never
+    called (the engine gates on ``enabled``); the per-stage totals are
+    kept either way, since the engine's snapshot reads them."""
 
     def __init__(self, engine, config: Optional[ProfilerConfig] = None
                  ) -> None:
         self.engine = engine
         self.config = config or ProfilerConfig()
+        # per tick stage: cumulative seconds (stages run between ticks,
+        # such as a flush's miss-check drain, accrue here directly), and
+        # the seconds of the tick in progress, or of the last one
+        self.stage_seconds: Dict[str, float] = defaultdict(float)
+        self.tick_stages: Dict[str, float] = defaultdict(float)
+        self._in_tick = False
         n = self.config.phase_buckets
         self.hist_base = 1e-6
         # per-phase cumulative seconds + log2 bucket counts (base 1us —
@@ -125,6 +277,22 @@ class TickPhaseProfiler:
         self._capture_armed: Optional[Dict[str, Any]] = None
         self._capture_remaining = 0
         self._capture_active: Optional[Dict[str, Any]] = None
+
+    # -- stages --------------------------------------------------------------
+
+    def stage(self, key: str) -> Stage:
+        """The ``orleans.tick.<key>`` stage: counted in the tick in
+        progress, or between ticks straight into the cumulative
+        totals."""
+        return Stage(_TICK_NAMES[key],
+                     self.tick_stages if self._in_tick
+                     else self.stage_seconds, key)
+
+    def tick(self) -> Stage:
+        """The ``orleans.tick`` stage around one tick: opening it starts
+        a fresh per-tick breakdown, closing it folds that breakdown into
+        the cumulative totals.  Its ``seconds`` is the tick's wall."""
+        return _TickStage(self)
 
     # -- configuration -------------------------------------------------------
 
@@ -333,6 +501,31 @@ class TickPhaseProfiler:
             "capture_active": self._capture_active is not None,
             "capture_events": list(self.capture_events),
         }
+
+
+class _TickStage(Stage):
+    __slots__ = ("profiler",)
+
+    def __init__(self, profiler: TickPhaseProfiler) -> None:
+        super().__init__("orleans.tick")
+        self.profiler = profiler
+
+    def open(self) -> Stage:
+        p = self.profiler
+        p.tick_stages = defaultdict(float)
+        p._in_tick = True
+        return Stage.open(self)
+
+    def close(self) -> float:
+        dt = Stage.close(self)  # closes any stage left open inside first
+        p = self.profiler
+        if p._in_tick:
+            p._in_tick = False
+            for k, v in p.tick_stages.items():
+                p.stage_seconds[k] += v
+        return dt
+
+    __enter__ = open
 
 
 # ---------------------------------------------------------------------------

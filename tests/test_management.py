@@ -196,6 +196,60 @@ def test_watchdog_detects_loop_stall(run):
     run(main())
 
 
+def test_watchdog_thread_names_the_stage_and_frame_of_a_stall(run,
+                                                              caplog):
+    """The watchdog thread sees a stall while it lasts: the innermost
+    stage open on the loop thread and that thread's frames go into the
+    [code 3001] warning, the incident bundle and the per-stage stall
+    seconds."""
+    async def main():
+        import logging
+        import time
+
+        from orleans_tpu.config import SiloConfig
+        from orleans_tpu.runtime.silo import Silo
+        from orleans_tpu.tensor.profiler import Stage
+
+        cfg = SiloConfig(name="stall-stage-test")
+        cfg.watchdog_period = 0.05
+        silo = Silo(config=cfg)
+        await silo.start()
+        try:
+            wd = silo.watchdog
+            wd.stall_threshold = 0.5
+            await asyncio.sleep(0.2)
+
+            def block_the_loop_in_a_stage():
+                with Stage("orleans.test.blocked"):
+                    time.sleep(1.5)
+
+            with caplog.at_level(logging.WARNING):
+                block_the_loop_in_a_stage()
+                await asyncio.sleep(0.3)  # the heartbeat reports
+            stall = wd.last_stall
+            assert stall is not None, "no stall reported"
+            assert stall["stage"] == "orleans.test.blocked"
+            assert stall["seconds"] >= 1.0
+            assert any("block_the_loop_in_a_stage" in f
+                       for f in stall["frames"]), stall["frames"]
+            assert wd.stall_seconds_by_stage["orleans.test.blocked"] \
+                >= 1.0
+            warned = [r.getMessage() for r in caplog.records
+                      if "[code 3001]" in r.getMessage()]
+            assert any("orleans.test.blocked" in m
+                       and "block_the_loop_in_a_stage" in m
+                       for m in warned), warned
+            bundle = list(silo.incidents)[-1]
+            assert "orleans.test.blocked" in bundle["reason"]
+            assert bundle["stall"]["frames"] == stall["frames"]
+            thread = wd._thread
+        finally:
+            await silo.stop()
+        assert not thread.is_alive()  # stopped with the silo
+
+    run(main())
+
+
 def test_manager_cli_commands(run, tmp_path, capsys):
     """The CLI joins via the shared membership table, runs commands
     through the management grain, and leaves (reference: OrleansManager)."""

@@ -82,11 +82,114 @@ def test_stage_map_covers_every_engine_stage_key(run):
         engine.send_batch("PresenceGrain", "heartbeat", keys,
                           _payload(keys, 1))
         await engine.flush()
-        for key in engine.stage_seconds:
+        for key in engine.profiler.stage_seconds:
             assert key in STAGE_TO_PHASE, \
                 f"engine stage {key!r} not mapped to a phase"
 
     run(main())
+
+
+def _host_events(trace_dir):
+    """(line, name, start_ns, end_ns) of every ``orleans.*`` host span
+    in the trace the profiler wrote under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    path, = Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("orleans."):
+                    out.append(((plane.name, i), e.name, e.start_ns,
+                                e.start_ns + e.duration_ns))
+    return out
+
+
+def test_tick_stages_reach_the_trace_nested_under_the_tick(run, tmp_path):
+    """Under a jax.profiler session every tick stage is a host span named
+    ``orleans.tick.<stage>`` inside its ``orleans.tick``, on the trace's
+    clock; the profiler's per-stage totals of a tick add up to no more
+    than the tick's wall, which the ``orleans.tick`` span measures."""
+    import jax
+
+    async def main():
+        engine = _engine()
+        keys = np.arange(256, dtype=np.int64)
+        fut = engine.send_batch("PresenceGrain", "heartbeat", keys,
+                                _payload(keys, 1), want_results=True)
+        await engine.flush()  # compiled before the trace
+        await fut
+        prof = engine.profiler
+        walls, sums = [], []
+        with jax.profiler.trace(str(tmp_path)):
+            for t in range(3):
+                fut = engine.send_batch("PresenceGrain", "heartbeat", keys,
+                                        _payload(keys, t + 2),
+                                        want_results=True)
+                engine.run_tick()
+                await fut
+                walls.append(engine.tick_durations[-1])
+                sums.append(sum(prof.tick_stages.values()))
+        return walls, sums
+
+    walls, sums = run(main())
+    for wall, total in zip(walls, sums):
+        assert 0.0 < total <= wall
+    events = _host_events(tmp_path)
+    ticks = [e for e in events if e[1] == "orleans.tick"]
+    stages = [e for e in events if e[1].startswith("orleans.tick.")]
+    names = {e[1] for e in stages}
+    assert {"orleans.tick.fanout", "orleans.tick.resolve",
+            "orleans.tick.apply", "orleans.tick.route",
+            "orleans.tick.results", "orleans.tick.checkpoint"} <= names
+    assert {n.split(".", 2)[2] for n in names} <= set(STAGE_TO_PHASE)
+    assert len(ticks) >= 3
+    for line, name, s0, s1 in stages:
+        assert any(t[0] == line and t[2] <= s0 and s1 <= t[3]
+                   for t in ticks), f"{name} outside every orleans.tick"
+    # the traced ticks' spans measure the walls the profiler folded
+    spans = sorted(t[3] - t[2] for t in ticks)[-3:]
+    for wall in walls:
+        assert any(abs(d * 1e-9 - wall) <= 0.1 * wall + 5e-4
+                   for d in spans), (wall, spans)
+
+
+def test_host_read_counts_one_blocking_read_per_site():
+    import jax.numpy as jnp
+
+    from orleans_tpu.tensor import profiler as P
+
+    calls0 = {s: P.sync_calls[s] for s in P.SYNC_SITES}
+    secs0 = {s: P.sync_seconds[s] for s in P.SYNC_SITES}
+    x = jnp.arange(4, dtype=jnp.int32) * 2
+    for site in P.SYNC_SITES:
+        out = P.host_read(site, {"x": x})
+        assert isinstance(out["x"], np.ndarray)
+        assert out["x"].tolist() == [0, 2, 4, 6]
+    for site in P.SYNC_SITES:
+        assert P.sync_calls[site] == calls0[site] + 1
+        assert P.sync_seconds[site] > secs0[site]
+        assert P.host_reads()[site]["calls"] == P.sync_calls[site]
+    with pytest.raises(KeyError):
+        P.host_read("not_a_site", x)  # span names are a fixed set
+
+
+def test_stage_closes_what_an_exception_left_open():
+    import threading
+
+    from orleans_tpu.tensor.profiler import Stage, innermost_stage
+
+    me = threading.get_ident()
+    totals = {"outer": 0.0, "inner": 0.0}
+    outer = Stage("orleans.test.outer", totals, "outer").open()
+    Stage("orleans.test.inner", totals, "inner").open()  # never closed
+    assert innermost_stage(me) == "orleans.test.inner"
+    outer.close()
+    assert innermost_stage(me) is None
+    assert totals["outer"] >= totals["inner"] > 0.0
+    assert outer.close() == 0.0  # a second close counts nothing
 
 
 def test_phase_histograms_mirror_into_registry(run):

@@ -403,3 +403,47 @@ def test_tcp_client_wide_key_batch_edge_throughput(run):
             await cluster.stop()
 
     run(main())
+
+
+def test_gateway_counts_the_frames_and_seconds_of_a_tcp_slab(run):
+    """The acceptor counts each vector slab frame it decodes, and the
+    loop-thread seconds of its decode, submit and reply stages."""
+
+    async def main():
+        import numpy as np
+        import samples.presence  # noqa: F401 — registers PresenceGrain
+
+        cluster = await TestingCluster(n_silos=1, transport="tcp").start()
+        try:
+            silo = cluster.silos[0]
+            acceptor = silo.gateway_acceptor
+            client = await GrainClient().connect(_gateway_endpoint(silo))
+            try:
+                n = 4096
+                keys = np.arange(n, dtype=np.int64)
+
+                def slab(t):
+                    return {"game": (keys % 16).astype(np.int32),
+                            "score": np.ones(n, np.float32),
+                            "tick": np.full(n, t, np.int32)}
+
+                # the first slab activates and compiles
+                await asyncio.wait_for(client.send_batch(
+                    "PresenceGrain", "heartbeat", keys, slab(1),
+                    want_results=True), timeout=60)
+                before = acceptor.snapshot()
+                for t in range(2, 5):
+                    await asyncio.wait_for(client.send_batch(
+                        "PresenceGrain", "heartbeat", keys, slab(t),
+                        want_results=True), timeout=60)
+                after = acceptor.snapshot()
+            finally:
+                await client.close()
+        finally:
+            await cluster.stop()
+        assert after["frames"] - before["frames"] == 3
+        for stage in ("decode", "submit", "reply"):
+            assert after["stage_seconds"][stage] \
+                > before["stage_seconds"].get(stage, 0.0), stage
+
+    run(main())

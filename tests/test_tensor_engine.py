@@ -346,7 +346,7 @@ def test_per_stage_tick_profiling_names_the_slow_stage(run):
             return orig(*a, **kw)
 
         arena.resolve_rows = slow_resolve
-        engine.stage_seconds.clear()
+        engine.profiler.stage_seconds.clear()
         engine.send_batch("AccumGrain", "add", keys,
                           {"v": np.float32(np.ones(64))})
         await engine.flush()
