@@ -271,6 +271,28 @@ declare("arena.shard_occupancy", KIND_GAUGE, "rows",
         "live rows in one mesh shard block (labels 'arena', 'shard') — "
         "the per-shard balance behind the multichip bench")
 
+# -- device fan-out (tensor/fanout.py) ---------------------------------------
+declare("fanout.width", KIND_GAUGE, "lanes",
+        "expansion width of a registered DeviceFanout's latest round "
+        "(label 'route' = SrcType.method)")
+declare("fanout.sized_rounds", KIND_COUNTER, "rounds",
+        "expansion rounds sized from their host keys to the ladder rung "
+        "at or above their exact degree sum (label 'route')")
+declare("fanout.full_width_rounds", KIND_COUNTER, "rounds",
+        "expansion rounds at the full CSR width: device-key sources and "
+        "overflow redeliveries (label 'route')")
+declare("fanout.lanes_needed", KIND_COUNTER, "lanes",
+        "exact degree sums of the sized rounds (label 'route')")
+declare("fanout.lanes_expanded", KIND_COUNTER, "lanes",
+        "lanes the sized rounds expanded; 1 - lanes_needed / "
+        "lanes_expanded is their padding share (label 'route')")
+declare("fanout.dropped_lanes", KIND_COUNTER, "events",
+        "publish source lanes parked by expansion-width overflow and "
+        "re-expanded at the next quiescence point (label 'route')")
+declare("fanout.redeliveries", KIND_COUNTER, "rounds",
+        "overflow redelivery rounds run for parked publish lanes "
+        "(label 'route')")
+
 # -- device streams plane (tensor/streams_plane.py) --------------------------
 declare("stream.published_events", KIND_COUNTER, "events",
         "stream-ingress publishes routed through a device subscription "

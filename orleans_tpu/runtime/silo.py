@@ -1072,6 +1072,9 @@ class Silo:
                       "dropped_lanes": ss["dropped_lanes"],
                       "redeliveries": ss["redeliveries"]},
                      {"route": f"{src_t}.{src_m}"}, "stream.")
+            for (src_t, src_m), (fanout, _, _) in eng._fanouts.items():
+                emit(fanout.snapshot(), {"route": f"{src_t}.{src_m}"},
+                     "fanout.")
             # device timers plane: wheel population + harvest health
             # (the dashboard's timers row reads these)
             tm = eng.timers.snapshot()

@@ -1201,14 +1201,18 @@ class TensorEngine:
             = subscriptions
 
     def _route_expand_push(self, expander, dst_type: str, dst_method: str,
-                           skeys, args: Any, mask, inject_tick: int
+                           skeys, args: Any, mask, inject_tick: int,
+                           keys_host: Optional[np.ndarray] = None
                            ) -> None:
         """Shared push-expansion tail for DeviceFanout registrations and
         stream-subscription routes: expand, enqueue the subscriber
         deliveries, and PARK the expansion's device-side overflow mask
         — dropped source lanes re-expand at the next quiescence point
-        with their original stamp (never a mid-tick error)."""
-        dst, gargs, valid = expander.expand(skeys, args, mask)
+        with their original stamp (never a mid-tick error).  A
+        DeviceFanout given the round's ``keys_host`` sizes the
+        expansion to their degree sum."""
+        sized = {} if keys_host is None else {"keys_host": keys_host}
+        dst, gargs, valid = expander.expand(skeys, args, mask, **sized)
         count, dropped = expander.take_drop()
         self._fanout_checks.append(_FanoutCheck(
             expander=expander, dst_type=dst_type, dst_method=dst_method,
@@ -1257,7 +1261,8 @@ class TensorEngine:
             else:
                 continue  # row-only batch with no kept keys: nothing to map
             self._route_expand_push(fanout, dst_type, dst_method,
-                                    skeys, b.args, mask, b.inject_tick)
+                                    skeys, b.args, mask, b.inject_tick,
+                                    keys_host=b.keys_host)
 
     def _expand_resolved_fanout(self, fan, batches: List[PendingBatch],
                                 resolved: List[Tuple]) -> None:
@@ -2838,6 +2843,10 @@ class TensorEngine:
             # subscription route is registered
             "streams": {f"{t}.{m}": r.snapshot()
                         for (t, m), r in self._stream_routes.items()},
+            # registered DeviceFanouts (tensor/fanout.py): expansion width,
+            # sized vs full-width rounds, needed vs expanded lanes
+            "fanouts": {f"{t}.{m}": fan.snapshot()
+                        for (t, m), (fan, _, _) in self._fanouts.items()},
             # ledger health only (no device transfer here — the bucket
             # counts come from engine.ledger.snapshot(), which pays the
             # ONE d2h fetch explicitly)

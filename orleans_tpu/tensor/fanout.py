@@ -11,22 +11,36 @@ whole batch of published messages expands into one flat (dst_key, args)
 tensor in a single jitted kernel.
 
 Raggedness with static shapes: per-message out-degrees are cumsum'd into
-offsets, and each of ``budget`` output slots binary-searches which source
+offsets, and each of ``width`` output slots binary-searches which source
 message it belongs to (`searchsorted` over the offsets — the standard XLA
 ragged-expansion idiom).  Slots past the real total are masked and carry
 ``KEY_SENTINEL`` keys, which the engine's resolve kernel already drops.
 
+Width rule.  The CSR width is the live edge count rounded up to a lane
+multiple of 256 (capped by ``budget``).  A round whose source keys the
+host knows (``expand(..., keys_host=...)``: the engine's host-key
+publish slabs) gets the exact need from a host mirror of the degrees —
+the sum of its lanes' degrees — and expands at the ladder rung at or
+above it (``exchange.ladder_ceil``, at least 256, at most the CSR
+width).  A per-graph high-water mark holds the widest rung used since
+the last rebuild, so the width never shrinks until the graph changes
+and the compile set stays one program per rung reached.  Every other
+round (device-key sources, redelivery, fused windows) expands at the
+full CSR width.
+
 Overflow contract (the ShardExchange discipline, tensor/exchange.py): a
-round whose expansion needs more slots than the CSR width loses NOTHING
+round whose expansion needs more slots than its width loses NOTHING
 and raises NOTHING mid-tick.  Source lanes whose whole expansion range
 does not fit deliver ZERO slots this round (never a partial prefix —
 that would double-deliver on retry) and come back as a device-side
 ``dropped`` mask; the engine parks it like a miss-check and re-expands
-exactly those lanes at the next quiescence point with their ORIGINAL
-``inject_tick`` stamp.  Each retry round completes at least one parked
-lane (a single lane's degree never exceeds the width, which is sized to
-the live edge count), so convergence is structural.  The storage budget
-(more EDGES than ``budget``) remains a hard config error at rebuild.
+exactly those lanes at the next quiescence point, at the full CSR
+width, with their ORIGINAL ``inject_tick`` stamp.  A sized round never
+parks below the CSR width: its width is at least its need, so every
+lane fits.  Each retry round completes at least one parked lane (a
+single lane's degree never exceeds the CSR width, which covers the live
+edge count), so convergence is structural.  The storage budget (more
+EDGES than ``budget``) remains a hard config error at rebuild.
 
 Mutation (follow/unfollow) is host-side control-plane; the device CSR is
 a mirror rebuilt lazily on first expand after a change — the same
@@ -36,33 +50,35 @@ index (arena.py device_index).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from orleans_tpu.tensor.exchange import ladder_ceil
 from orleans_tpu.tensor.vector_grain import (
     KEY_SENTINEL,
     ones_mask as _ones_mask,
 )
 
 
-@jax.jit
-def _expand_kernel(csr_keys, csr_offsets, csr_dst, src_keys, valid):
-    """Expand [m] source messages into [budget] destination slots.
+@functools.partial(jax.jit, static_argnames=("width",))
+def _expand_kernel(csr_keys, csr_offsets, csr_dst, src_keys, valid, *,
+                   width):
+    """Expand [m] source messages into [width] destination slots.
 
-    Returns (dst_keys int32[budget], src_index int32[budget],
-    out_valid bool[budget], total int32, src_dropped bool[m],
+    Returns (dst_keys int32[width], src_index int32[width],
+    out_valid bool[width], total int32, src_dropped bool[m],
     n_dropped int32) where ``src_index[j]`` is the source message each
     slot's args are gathered from and ``total`` is the true (unpadded)
     number of expanded messages.  A source lane whose expansion range
-    extends past ``budget`` materializes NO slots (all-or-nothing per
+    extends past ``width`` materializes NO slots (all-or-nothing per
     lane — a partial prefix would double-deliver on redelivery) and is
     flagged in ``src_dropped`` for the engine's park-and-redeliver
-    path."""
+    path.  ``width`` is static and may be shorter than the CSR."""
     n = csr_keys.shape[0]
-    budget = _budget_of(csr_dst)  # static: taken from a closure-free helper
     idx = jnp.clip(jnp.searchsorted(csr_keys, src_keys), 0, n - 1)
     hit = valid & (csr_keys[idx] == src_keys)
     deg = jnp.where(hit, csr_offsets[idx + 1] - csr_offsets[idx], 0)
@@ -70,23 +86,19 @@ def _expand_kernel(csr_keys, csr_offsets, csr_dst, src_keys, valid):
     offs = jnp.cumsum(deg)                      # inclusive: msgs ≤ i
     total = offs[-1] if offs.shape[0] else jnp.int32(0)
     # all-or-nothing per source lane: lane i's slots are
-    # [offs[i]-deg[i], offs[i]) — it fits iff offs[i] <= budget
-    src_dropped = hit & (deg > 0) & (offs > budget)
+    # [offs[i]-deg[i], offs[i]) — it fits iff offs[i] <= width
+    src_dropped = hit & (deg > 0) & (offs > width)
     n_dropped = jnp.sum(src_dropped.astype(jnp.int32))
-    j = jnp.arange(budget, dtype=jnp.int32)
+    j = jnp.arange(width, dtype=jnp.int32)
     src_index = jnp.searchsorted(offs, j, side="right").astype(jnp.int32)
     src_c = jnp.clip(src_index, 0, jnp.maximum(src_keys.shape[0] - 1, 0))
     before = jnp.where(src_c > 0, offs[src_c - 1], 0)
     e = start[src_c] + (j - before)
-    out_valid = (j < total) & (offs[src_c] <= budget)
+    out_valid = (j < total) & (offs[src_c] <= width)
     dst = jnp.where(out_valid,
-                    csr_dst[jnp.clip(e, 0, jnp.maximum(budget - 1, 0))],
+                    csr_dst[jnp.clip(e, 0, max(csr_dst.shape[0] - 1, 0))],
                     KEY_SENTINEL)
     return dst, src_c, out_valid, total, src_dropped, n_dropped
-
-
-def _budget_of(csr_dst):
-    return csr_dst.shape[0]
 
 
 def _group_ranges(sorted_vals: np.ndarray):
@@ -123,6 +135,13 @@ class DeviceFanout:
         self._csr_keys: Optional[jnp.ndarray] = None
         self._csr_offsets: Optional[jnp.ndarray] = None
         self._csr_dst: Optional[jnp.ndarray] = None
+        # host mirror of the CSR's sources: sorted keys and their
+        # degrees, for a round's exact need (``need``) with no device read
+        self._host_keys = np.zeros(0, np.int64)
+        self._host_deg = np.zeros(0, np.int64)
+        self._csr_width = 0
+        # widest sized-round rung since the last rebuild
+        self._high_water = 0
         # the latest expand()'s parked overflow: (n_dropped device
         # scalar, src_dropped device bool[m]) — consumed by the caller
         # (engine parks a _FanoutCheck; fused folds the count into the
@@ -132,6 +151,15 @@ class DeviceFanout:
         # cumulative host-side stats, folded at drain points
         self.dropped_lanes = 0
         self.redeliveries = 0
+        # rounds sized from their host keys, against full-CSR-width
+        # rounds; the sized rounds' exact need and expanded lanes (their
+        # padding share is 1 - lanes_needed / lanes_expanded); the
+        # latest round's width
+        self.sized_rounds = 0
+        self.full_width_rounds = 0
+        self.lanes_needed = 0
+        self.lanes_expanded = 0
+        self.width = 0
 
     # -- control plane (host) ----------------------------------------------
 
@@ -189,24 +217,28 @@ class DeviceFanout:
         keys = np.fromiter(srcs, dtype=np.int64, count=len(srcs))
         if (keys >= np.int64(KEY_SENTINEL)).any() or (keys < 0).any():
             raise OverflowError("fanout src keys must be in [0, 2**31-1)")
-        # expansion width: how many output slots one expand round gets.
-        # Sized to the live edge count (lane-aligned), NOT the storage
-        # budget — a static graph then pads < 256 dead lanes per round
-        # instead of (budget - edges).  The budget stays the hard cap on
-        # STORED edges; a round with duplicate src keys that needs more
-        # than `width` slots parks the overflowing source lanes and
-        # re-expands them at the next quiescence point (never silent
-        # truncation, never a mid-tick error).  width >= any single
-        # lane's degree (degree <= edge_count <= width), so every retry
-        # round completes at least one lane — convergence is structural.
+        # CSR width: the most output slots one round gets, and the width
+        # of every round whose host keys are unknown.  Sized to the live
+        # edge count (lane-aligned), NOT the storage budget, which stays
+        # the hard cap on STORED edges.  A round with known host keys
+        # expands at its need's rung instead (``_round_width``), never
+        # narrower than the high-water mark, which resets here because
+        # the degrees changed.  A lane that does not fit its round parks
+        # and re-expands at this full width; width >= any single lane's
+        # degree (degree <= edge_count <= width), so every retry round
+        # completes at least one lane — convergence is structural.
         width = min(self.budget,
                     max(256, -(-max(1, self.edge_count) // 256) * 256))
+        self._host_keys = keys
+        self._csr_width = width
+        self._high_water = 0
         if not srcs:
             # sentinel row so the kernel never gathers from an empty array;
             # KEY_SENTINEL can't match a valid src key (they are < it)
             keys_np = np.array([KEY_SENTINEL], np.int32)
             offsets = np.zeros(2, np.int32)
             dst_np = np.full(width, KEY_SENTINEL, np.int32)
+            self._host_deg = np.zeros(0, np.int64)
         else:
             offsets = np.zeros(len(srcs) + 1, dtype=np.int32)
             dst_np = np.full(width, KEY_SENTINEL, dtype=np.int32)
@@ -217,6 +249,7 @@ class DeviceFanout:
                 pos += len(d)
                 offsets[i + 1] = pos
             keys_np = keys.astype(np.int32)
+            self._host_deg = np.diff(offsets).astype(np.int64)
         ck = jnp.asarray(keys_np)
         co = jnp.asarray(offsets)
         cd = jnp.asarray(dst_np)
@@ -230,26 +263,64 @@ class DeviceFanout:
 
     # -- data plane ----------------------------------------------------------
 
+    def need(self, keys_host: np.ndarray) -> int:
+        """Exact expansion need of a round whose source keys are
+        ``keys_host``: the sum of their degrees, from the host mirror.
+        Keys with no followers count 0; a duplicate key counts once per
+        lane, as the expansion delivers once per lane.  Every lane
+        counts, masked or not: an over-estimate only pads."""
+        if self._dirty:
+            self._rebuild()
+        keys = np.asarray(keys_host, dtype=np.int64)
+        if len(self._host_keys) == 0 or len(keys) == 0:
+            return 0
+        idx = np.minimum(np.searchsorted(self._host_keys, keys),
+                         len(self._host_keys) - 1)
+        hit = self._host_keys[idx] == keys
+        return int(self._host_deg[idx[hit]].sum())
+
+    def _round_width(self, need: int) -> int:
+        """A sized round's width: its rung, raised to the high-water
+        mark so a narrower rung never compiles after a wider one."""
+        rung = min(self._csr_width, max(256, ladder_ceil(need)))
+        self._high_water = max(self._high_water, rung)
+        return self._high_water
+
     def expand(self, src_keys: jnp.ndarray, args: Any,
-               mask: Optional[jnp.ndarray] = None
+               mask: Optional[jnp.ndarray] = None,
+               keys_host: Optional[np.ndarray] = None
                ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
         """(src message keys [m], args pytree [m,...]) → (dst keys
-        [budget], gathered args [budget,...] + ``src_key``, valid mask).
+        [width], gathered args [width,...] + ``src_key``, valid mask).
 
-        Scalar arg leaves broadcast (same convention as the engine's
-        kernels).  Source lanes whose expansion does not fit this
-        round's width deliver NOTHING now; their device-side dropped
-        mask parks via ``take_drop()`` (the engine re-expands exactly
-        those lanes at the next quiescence point with the original
-        inject stamp — the ShardExchange redelivery contract)."""
+        ``keys_host``, the same source keys on the host, sizes the
+        round to its exact need (module docstring); without it the
+        round expands at the full CSR width.  Scalar arg leaves
+        broadcast (same convention as the engine's kernels).  Source
+        lanes whose expansion does not fit this round's width deliver
+        NOTHING now; their device-side dropped mask parks via
+        ``take_drop()`` (the engine re-expands exactly those lanes at
+        the next quiescence point with the original inject stamp — the
+        ShardExchange redelivery contract)."""
         if self._dirty:
             ck, co, cd = self._rebuild()
         else:
             ck, co, cd = self._csr_keys, self._csr_offsets, self._csr_dst
         if mask is None:
             mask = _ones_mask(src_keys.shape[0])
+        if keys_host is None:
+            width = cd.shape[0]
+            if not isinstance(src_keys, jax.core.Tracer):
+                self.full_width_rounds += 1
+        else:
+            need = self.need(keys_host)
+            width = self._round_width(need)
+            self.sized_rounds += 1
+            self.lanes_needed += need
+            self.lanes_expanded += width
+        self.width = width
         dst, src_index, out_valid, _total, src_dropped, n_dropped = \
-            _expand_kernel(ck, co, cd, src_keys, mask)
+            _expand_kernel(ck, co, cd, src_keys, mask, width=width)
         self._pending_drops.append((n_dropped, src_dropped))
         gathered = jax.tree_util.tree_map(
             lambda a: a if jnp.ndim(a) == 0 else jnp.asarray(a)[src_index],
@@ -277,3 +348,12 @@ class DeviceFanout:
             total += int(n_dropped)
         self.dropped_lanes += total
         return total
+
+    def snapshot(self) -> Dict[str, Any]:
+        return {"width": self.width,
+                "sized_rounds": self.sized_rounds,
+                "full_width_rounds": self.full_width_rounds,
+                "lanes_needed": self.lanes_needed,
+                "lanes_expanded": self.lanes_expanded,
+                "dropped_lanes": self.dropped_lanes,
+                "redeliveries": self.redeliveries}

@@ -446,7 +446,7 @@ class DeviceSubscriptions:
         if mask is None:
             mask = _ones_mask(src_keys.shape[0])
         dst, src_index, out_valid, _total, src_dropped, n_dropped = \
-            _expand_kernel(ck, co, cd, src_keys, mask)
+            _expand_kernel(ck, co, cd, src_keys, mask, width=cd.shape[0])
         self._pending_drops.append((n_dropped, src_dropped))
         gathered = jax.tree_util.tree_map(
             lambda a: a if jnp.ndim(a) == 0 else jnp.asarray(a)[src_index],

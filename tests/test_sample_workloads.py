@@ -118,6 +118,101 @@ def test_fanout_overflow_parks_lanes_not_raises():
                     {"v": jnp.zeros(1)})
 
 
+def _triples(dst, gargs, valid):
+    dst, valid = np.asarray(dst), np.asarray(valid)
+    return sorted(zip(dst[valid].tolist(),
+                      np.asarray(gargs["src_key"])[valid].tolist(),
+                      np.asarray(gargs["v"])[valid].tolist()))
+
+
+def test_fanout_sized_expansion_matches_full_width():
+    """A round sized from its host keys expands at the ladder rung at or
+    above its exact degree sum and delivers the same (dst, src_key,
+    args) triples as the full-CSR-width expansion."""
+    import jax.numpy as jnp
+
+    from orleans_tpu.tensor.exchange import ladder_ceil
+
+    fan = build_follow_graph(400, mean_followers=8.0, seed=5)
+    rng = np.random.default_rng(7)
+    # duplicates, and keys past the accounts (no followers): need 0 each
+    keys = np.concatenate([rng.integers(0, 400, 48), [5, 5, 401, 9999]])
+    src = jnp.asarray(keys.astype(np.int32))
+    args = {"v": jnp.asarray(np.arange(len(keys), dtype=np.int32) * 3)}
+    deg = np.asarray([len(fan.followers_of(int(k))) for k in keys])
+    need = fan.need(keys)
+    assert need == int(deg.sum())
+
+    full = fan.expand(src, args)
+    full_width = fan.width
+    assert fan.take_drop()[0] == 0
+    sized = fan.expand(src, args, keys_host=keys)
+    assert fan.take_drop()[0] == 0
+    assert fan.width == max(256, ladder_ceil(need)) < full_width
+    assert np.asarray(sized[0]).shape == (fan.width,)
+    assert _triples(*sized) == _triples(*full)
+    assert len(_triples(*sized)) == need
+    assert (fan.sized_rounds, fan.full_width_rounds) == (1, 1)
+    assert (fan.lanes_needed, fan.lanes_expanded) == (need, fan.width)
+
+
+@pytest.mark.parametrize("mutate", ["follow", "unfollow", "add_edges"])
+def test_fanout_sized_width_high_water_mark(mutate):
+    """A sized round never expands narrower than the widest rung since
+    the last rebuild; a graph change resets the mark."""
+    import jax.numpy as jnp
+
+    fan = DeviceFanout()
+    fan.add_edges(np.full(300, 1), np.arange(1000, 1300))
+    fan.add_edges(np.full(5, 2), np.arange(2000, 2005))
+
+    def round_width(key):
+        keys = np.array([key], np.int64)
+        fan.expand(jnp.asarray(keys.astype(np.int32)),
+                   {"v": jnp.zeros(1, jnp.int32)}, keys_host=keys)
+        assert int(fan.take_drop()[0]) == 0
+        return fan.width
+
+    assert round_width(2) == 256
+    assert round_width(1) == 384            # ladder_ceil(300)
+    assert round_width(2) == 384            # the mark holds
+    assert round_width(1) == 384
+    if mutate == "follow":
+        fan.follow(2, 2005)
+    elif mutate == "unfollow":
+        fan.unfollow(1, 1000)
+    else:
+        fan.add_edges(np.array([2]), np.array([2006]))
+    assert round_width(2) == 256            # rebuilt: the mark reset
+
+
+def test_fanout_sized_round_capped_at_csr_width_parks():
+    """Duplicate host keys whose need passes the CSR width expand at the
+    CSR width and park the lane that does not fit, exactly like the
+    full-width overflow; the redelivery completes it."""
+    import jax.numpy as jnp
+
+    fan = DeviceFanout()
+    fan.add_edges(np.full(300, 1), np.arange(100, 400))   # CSR width 512
+    keys = np.array([1, 1], np.int64)
+    src = jnp.asarray(keys.astype(np.int32))
+    assert fan.need(keys) == 600
+    dst, _g, valid = fan.expand(src, {"v": jnp.zeros(2)}, keys_host=keys)
+    assert fan.width == 512
+    n_dropped, dropped = fan.take_drop()
+    assert int(n_dropped) == 1
+    assert np.asarray(dropped).tolist() == [False, True]
+    assert sorted(np.asarray(dst)[np.asarray(valid)].tolist()) \
+        == list(range(100, 400))
+    dst2, _g2, valid2 = fan.expand(src, {"v": jnp.zeros(2)},
+                                   jnp.asarray(np.array(dropped)))
+    assert int(fan.take_drop()[0]) == 0
+    assert sorted(np.asarray(dst2)[np.asarray(valid2)].tolist()) \
+        == list(range(100, 400))
+    assert (fan.sized_rounds, fan.full_width_rounds) == (1, 1)
+    assert fan.overflow_check() == 0
+
+
 # ---------------------------------------------------------------------------
 # Chirper
 # ---------------------------------------------------------------------------
@@ -173,6 +268,96 @@ def test_chirper_power_law_load(run):
         # power-law sanity: the most-followed account dominates the median
         deg = np.asarray([len(fan.followers_of(s)) for s in range(200)])
         assert deg.max() >= 10 * max(1, int(np.median(deg)))
+
+    run(main())
+
+
+def test_chirper_host_key_slabs_sized_exact(run):
+    """Publish slabs handed to the engine as the gateway hands them
+    (host keys through ``send_batch``) expand at their degree sum's
+    rung, and every follower receives every chirp exactly once."""
+
+    async def main():
+        engine = TensorEngine()
+        n, lanes, n_slabs = 300, 64, 6
+        fan = build_follow_graph(n, mean_followers=8.0, seed=11)
+        engine.register_fanout("ChirperAccount", "publish", fan,
+                               "ChirperAccount", "new_chirp")
+        arena = engine.arena_for("ChirperAccount")
+        arena.reserve(n)
+        arena.resolve_rows(np.arange(n, dtype=np.int64))
+
+        order = np.random.default_rng(4).permutation(n)
+        published = np.zeros(n, np.int64)
+        newest = np.full(n, -1, np.int64)
+        for i in range(n_slabs):
+            keys = order[(i * lanes + np.arange(lanes)) % n].astype(np.int64)
+            ids = (i * lanes + np.arange(lanes)).astype(np.int32)
+            fut = engine.send_batch("ChirperAccount", "publish", keys,
+                                    {"chirp_id": ids}, want_results=True)
+            await engine.flush()
+            await fut
+            published[keys] += 1
+            newest[keys] = ids
+
+        # the per-follower oracle
+        received = np.zeros(n, np.int64)
+        checksum = np.zeros(n, np.int64)
+        last = np.full(n, -1, np.int64)
+        for s in range(n):
+            for d in fan.followers_of(s):
+                received[d] += published[s]
+                checksum[d] += published[s] * (s % 97)
+                if published[s]:
+                    last[d] = max(last[d], newest[s])
+        rows = arena.resolve_rows(np.arange(n, dtype=np.int64))
+        st = {f: np.asarray(arena.state[f])[rows]
+              for f in ("published", "received", "last_chirp", "checksum")}
+        np.testing.assert_array_equal(st["published"], published)
+        np.testing.assert_array_equal(st["received"], received)
+        np.testing.assert_array_equal(st["last_chirp"], last)
+        np.testing.assert_array_equal(st["checksum"], checksum)
+        assert fan.sized_rounds == n_slabs
+        assert fan.full_width_rounds == 0
+        assert fan.dropped_lanes == 0
+        assert fan.lanes_needed == int(received.sum())
+        assert fan.width < -(-fan.edge_count // 256) * 256
+        assert engine.snapshot()["fanouts"]["ChirperAccount.publish"] \
+            == fan.snapshot()
+
+    run(main())
+
+
+def test_fanout_metrics_collected(run):
+    """The silo's metrics collection reports each registered fan-out's
+    width and round counters (strict: an undeclared name raises)."""
+    from orleans_tpu.config import SiloConfig
+    from orleans_tpu.runtime.silo import Silo
+
+    async def main():
+        silo = Silo(config=SiloConfig(name="fmetrics"))
+        await silo.start()
+        try:
+            engine = silo.tensor_engine
+            fan = DeviceFanout(budget=64)
+            fan.add_edges(np.array([1, 1, 2]), np.array([2, 3, 3]))
+            engine.register_fanout("ChirperAccount", "publish", fan,
+                                   "ChirperAccount", "new_chirp")
+            engine.arena_for("ChirperAccount").reserve(4)
+            engine.send_batch("ChirperAccount", "publish",
+                              np.array([1, 2], np.int64),
+                              {"chirp_id": np.array([5, 6], np.int32)})
+            await engine.flush()
+            snap = silo.collect_metrics()
+            for name in ("fanout.sized_rounds", "fanout.full_width_rounds",
+                         "fanout.lanes_needed", "fanout.lanes_expanded",
+                         "fanout.dropped_lanes", "fanout.redeliveries"):
+                assert name in snap["counters"], name
+            assert "fanout.width" in snap["gauges"]
+            assert (fan.sized_rounds, fan.lanes_needed, fan.width) \
+                == (1, 3, 64)
+        finally:
+            await silo.stop()
 
     run(main())
 
