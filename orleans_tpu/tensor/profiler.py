@@ -128,10 +128,11 @@ class Stage:
         stack = self._stack
         if stack is None:
             return 0.0  # not open
-        dt = _perf_counter() - self._t0
         self._stack = None
+        # inner stages close first, so this stage's seconds hold theirs
         for inner in reversed(stack[self._depth + 1:]):
             inner.close()
+        dt = _perf_counter() - self._t0
         del stack[self._depth:]
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
