@@ -420,6 +420,11 @@ class TensorEngineConfig:
     bucket_sizes: tuple = (256, 4096, 32768, 131072, 262144, 524288,
                            1 << 20)
     mesh_axis: str = "grains"
+    # local devices the silo's engine spans: above 1 the silo builds a
+    # 1-D mesh on mesh_axis over the first mesh_devices local devices,
+    # so the arenas are sharded over them and cross-shard messages take
+    # the device exchange; 1 builds no mesh (one device, no exchange)
+    mesh_devices: int = 1
     # device-resident cross-shard routing (tensor/exchange.py): under a
     # mesh, device batches are bucketed by destination shard and moved
     # with ONE lax.all_to_all inside the compiled program, so the step

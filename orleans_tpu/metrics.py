@@ -466,6 +466,10 @@ declare("engine.donation_fallbacks", KIND_COUNTER, "programs",
 declare("engine.latency_budget_s", KIND_GAUGE, "seconds",
         "the live target_tick_latency budget (0 = unbounded); the "
         "dashboard judges the device-ledger p99 against it")
+declare("tensor.shards", KIND_GAUGE, "shards",
+        "mesh shards the silo's engine spans (tensor.mesh_devices): 1 "
+        "runs on one device with no exchange; above 1 the arenas are "
+        "sharded and cross-shard messages take the device exchange")
 
 # -- device cost plane (tensor/profiler.py + tensor/memledger.py) ------------
 declare("engine.phase_s", KIND_HISTOGRAM, "seconds",

@@ -70,6 +70,27 @@ _SYSTEM_TARGET_CODES: Dict[str, int] = {
 _CODE_TO_NAME = {v: k for k, v in _SYSTEM_TARGET_CODES.items()}
 
 
+def engine_mesh(config):
+    """The mesh the silo's tensor engine spans: 1-D on
+    ``config.mesh_axis`` over the first ``config.mesh_devices`` local
+    devices, or None at 1 (the engine then runs on one device)."""
+    want = int(config.mesh_devices)
+    if want < 1:
+        raise ValueError(f"tensor.mesh_devices must be at least 1, "
+                         f"got {want}")
+    if want == 1:
+        return None
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    devices = jax.local_devices()
+    if len(devices) < want:
+        raise ValueError(f"tensor.mesh_devices asks for {want} devices, "
+                         f"but {len(devices)} local devices are visible")
+    return Mesh(np.array(devices[:want]), (config.mesh_axis,))
+
+
 class _CatalogTarget:
     """Catalog system target: remote existence checks + admin ops
     (reference: Catalog as SystemTarget, Constants catalog=14)."""
@@ -324,6 +345,8 @@ class Silo:
         if self.config.tensor.enabled:
             from orleans_tpu.tensor.engine import TensorEngine
             self.tensor_engine = TensorEngine(self, self.config.tensor,
+                                              mesh=engine_mesh(
+                                                  self.config.tensor),
                                               metrics=self.config.metrics,
                                               profiler=self.config.profiler)
         else:
@@ -1151,6 +1174,7 @@ class Silo:
                   "donation_fallbacks": eng.donation_fallbacks,
                   "latency_budget_s": eng.config.target_tick_latency},
                  None, "engine.")
+            reg.gauge("tensor.shards").set(float(eng.n_shards))
             # compile-churn attribution: cause-coded counters replace
             # the bare compiles int as the actionable churn signal
             ct = eng.compile_tracker

@@ -207,6 +207,9 @@ class _ExchangeCheck:
     # checks from older paths still drain — fold_stats is
     # width-agnostic)
     stats: jnp.ndarray
+    # lanes of the exchanged batch: the demand tail is read per valid
+    # lane of it
+    width: int
     inject_tick: int = -1
     # a disengaged-exchange probe: stats fold at drain, but the batch
     # delivered through the normal path — NOTHING may redeliver
@@ -2065,7 +2068,7 @@ class TensorEngine:
                 # the demand tail sizes future caps for THIS site —
                 # occupancy-sized buckets (tensor/exchange.py)
                 xch.fold_stats(row, site=(c.type_name, c.method),
-                               scale=c.scale)
+                               scale=c.scale, width=c.width)
             if c.measure_only or int(row[1]) == 0:
                 continue
             if xch is not None:
@@ -2419,6 +2422,7 @@ class TensorEngine:
                     args=None, dropped=None,
                     stats=xch._probe(arena, rows, base,
                                      (type_name, method)),
+                    width=int(rows.shape[0]),
                     measure_only=True, scale=scale))
 
         exchanged = False
@@ -2435,6 +2439,7 @@ class TensorEngine:
             base = mask if mask is not None \
                 else _mask_for(rows.shape[0])
             orig_args = args
+            width = int(rows.shape[0])
             pre = batches[0].pre_exchange if len(batches) == 1 else None
             if pre is not None and pre[5] == arena.generation \
                     and pre[6] == arena.eviction_epoch \
@@ -2467,7 +2472,7 @@ class TensorEngine:
             self._exchange_checks.append(_ExchangeCheck(
                 type_name=type_name, method=method, keys=keys_cat,
                 args=orig_args, dropped=dropped, stats=stats,
-                inject_tick=inj))
+                width=width, inject_tick=inj))
             if ledger.enabled and inj >= 0:
                 # post-exchange accounting: exactly the lanes delivered
                 # this tick (dropped lanes count at redelivery)

@@ -29,8 +29,10 @@ overflow included) and a per-(type, method) estimator quantizes the
 observed peak onto a small ladder ({2^k} ∪ {3·2^(k-1)}, ≤33% overshoot,
 O(log) rungs): caps GROW immediately when demand overflows (the parked
 redelivery below is the correctness net while the estimate lags) and
-SHRINK only after ``exchange_shrink_patience`` calm drains, so steady
-traffic never churns compiles.  A site whose measured demand is zero
+SHRINK only after ``exchange_shrink_patience`` calm drains, to a rung at
+most half the grant, so steady traffic never churns compiles.  Demand is
+kept per valid lane (``REF_LANES``), so one estimate plans every batch
+width a site sees.  A site whose measured demand is zero
 plans ``cap == 0`` and the exchange short-circuits to a classification
 pass — no sort, no all_to_all, output width == input width — which is
 also what a host-side shard-ALIGNED batch (``align_plan``) gets by
@@ -74,6 +76,14 @@ from jax.sharding import PartitionSpec
 #: wildly different cross-shard demand (an aligned injection has none;
 #: its fan-in delivery carries the workload's whole cross ratio)
 Site = Tuple[str, str]
+
+#: the occupancy estimators keep a site's demand per this many valid
+#: lanes of a source shard's slice.  A site's batches come at many
+#: widths (a tick merges however many slabs arrived), and the share of
+#: a slice's valid lanes bound for each destination is what holds from
+#: one width to the next; a grant is scaled to a batch's own valid
+#: lanes when it is planned
+REF_LANES = 1 << 20
 
 
 def pow2ceil(n: int) -> int:
@@ -121,14 +131,30 @@ def ladder_ceil(n: int) -> int:
     return three if three >= n else p
 
 
+def _to_ref(v, valid: float) -> np.ndarray:
+    """Demand out of ``valid`` lanes of a slice, per ``REF_LANES``."""
+    return np.ceil(np.asarray(v, np.float64)
+                   * (REF_LANES / max(float(valid), 1.0))).astype(np.int64)
+
+
+def _scaled(rung: int, valid: float) -> int:
+    """A grant kept per ``REF_LANES`` valid lanes, as the ladder rung
+    that covers it over a slice of ``valid`` lanes."""
+    return ladder_ceil(int(np.ceil(int(rung) * float(valid) / REF_LANES)))
+
+
 class _SiteEstimator:
     """Measured bucket demand for one (type, method) exchange site.
 
     Tracks the per-destination-shard demand peak and grants a quantized
     cap: growth is immediate (an undersized grant only costs a parked
     redelivery, but staying undersized would cost one EVERY tick);
-    shrink waits for ``patience`` consecutive calm observations below
-    half the grant, so a noisy steady state never flaps compiles."""
+    shrink waits for ``patience`` consecutive calm observations and a
+    calm rung at most half the grant, so a steady state whose demand
+    sits at a rung boundary never flaps compiles.  Demand arrives per
+    ``REF_LANES`` valid lanes of a source slice
+    (``ShardExchange.observe_need`` scales it), so batches of different
+    widths read alike."""
 
     def __init__(self, n_shards: int) -> None:
         self.n_shards = n_shards
@@ -145,14 +171,26 @@ class _SiteEstimator:
         self.recv_grant: Optional[int] = None       # inbound rung
         self.peak_inbound = np.zeros(n_shards, np.int64)
         self.last_need = np.zeros(n_shards, np.int64)
+        # the largest share of a batch's lanes that were valid, and the
+        # most valid lanes a source slice held: what a batch is planned
+        # over (ShardExchange._valid_lanes)
+        self.fill = 0.0
+        self.valid = 0
         self._window_pd = np.zeros(n_shards, np.int64)
         self._window_in = np.zeros(n_shards, np.int64)
         self._obs_pd = 0
 
     @staticmethod
     def _rungs(vec: np.ndarray, headroom: float) -> np.ndarray:
-        return np.array([ladder_ceil(int(np.ceil(float(v) * headroom)))
-                         if v > 0 else 0 for v in vec], np.int64)
+        """Per-destination rungs; a rung one ladder step below the top
+        one takes the top rung.  Under even traffic every destination's
+        share straddles the same rung boundary, and noise alone would
+        otherwise move a rung (and every plan) long after the first
+        observations; a destination far below the top keeps its own."""
+        rungs = np.array([ladder_ceil(int(np.ceil(float(v) * headroom)))
+                          if v > 0 else 0 for v in vec], np.int64)
+        top = rungs.max(initial=0)
+        return np.where(3 * rungs >= 2 * top, top, rungs)
 
     def observe(self, need: np.ndarray, headroom: float,
                 patience: int, inbound: Optional[np.ndarray] = None
@@ -188,7 +226,7 @@ class _SiteEstimator:
                                            * headroom)))
             self._window = np.zeros(self.n_shards, np.int64)
             self._obs = 0
-            if calm < self.grant:
+            if calm < self.grant and 2 * calm <= self.grant:
                 self.grant = calm
                 changed = True
         # per-destination grants: any rung grows immediately; shrink
@@ -218,9 +256,13 @@ class _SiteEstimator:
             self._window_pd = np.zeros(self.n_shards, np.int64)
             self._window_in = np.zeros(self.n_shards, np.int64)
             self._obs_pd = 0
-            if (calm_pd < self.grants).any() or calm_r < self.recv_grant:
-                self.grants = np.minimum(self.grants, calm_pd)
-                self.recv_grant = min(self.recv_grant, calm_r)
+            shrink = (calm_pd < self.grants) & (2 * calm_pd <= self.grants)
+            shrink_r = calm_r < self.recv_grant \
+                and 2 * calm_r <= self.recv_grant
+            if shrink.any() or shrink_r:
+                self.grants = np.where(shrink, calm_pd, self.grants)
+                if shrink_r:
+                    self.recv_grant = calm_r
                 changed_pd = True
         return changed, changed_pd
 
@@ -231,6 +273,9 @@ class _SiteEstimator:
                 "recv_grant": self.recv_grant,
                 "peak_need": self.peak.tolist(),
                 "peak_inbound": self.peak_inbound.tolist(),
+                "per_valid_lanes": REF_LANES,
+                "fill": round(self.fill, 4),
+                "valid": self.valid,
                 "observations": self.observations}
 
 
@@ -374,16 +419,16 @@ class ShardExchange:
         path, so the parked check must never redeliver
         (measure_only)."""
         n = self.n_shards
-        m = int(rows.shape[0])
         shard_capacity = int(arena.shard_capacity)
-        L = m // n if m in self._transport_widths and m % n == 0 \
-            else ladder_ceil(-(-m // n))
+        L = self.slice_lanes(int(rows.shape[0]))
         key = ("probe", L, shard_capacity)
         fn = self._jit_cache.get(key)
         if fn is None:
             m_pad = n * L
 
-            def call(rows, mask):
+            # named for the device trace, which groups device time by
+            # program: this one shows as ``jit__exchange_probe``
+            def _exchange_probe(rows, mask):
                 def pad(x, fill):
                     if x.shape[0] == m_pad:
                         return x
@@ -402,18 +447,20 @@ class ShardExchange:
                     jnp.int32(0),
                     jnp.sum(valid.astype(jnp.int32)),
                 ]), g, g])
-            fn = jax.jit(call)
+            fn = jax.jit(_exchange_probe)
             self._jit_cache[key] = fn
         return fn(jnp.asarray(rows), mask)
 
     # -- occupancy-sized planning -------------------------------------------
 
     def observe_need(self, site: Site, need: np.ndarray,
-                     inbound: Optional[np.ndarray] = None) -> None:
-        """Fold one drained per-destination demand vector for a site.
-        A [2n] vector (max-half ‖ sum-half) may arrive as one array in
-        ``need``; it is split here so every drain path can stay
-        width-agnostic."""
+                     inbound: Optional[np.ndarray] = None, *,
+                     valid: int, width: int) -> None:
+        """Fold one drained per-destination demand vector for a site,
+        measured on a ``width``-lane batch of which ``valid`` lanes
+        were valid.  A [2n] vector (max-half ‖ sum-half) may arrive as
+        one array in ``need``; it is split here so every drain path can
+        stay width-agnostic."""
         cfg = self.engine.config
         need = np.asarray(need)
         n = self.n_shards
@@ -422,9 +469,14 @@ class ShardExchange:
         est = self.estimators.get(site)
         if est is None:
             est = self.estimators[site] = _SiteEstimator(self.n_shards)
+        per_slice = valid / n
+        est.fill = max(est.fill, min(1.0, valid / max(int(width), 1)))
+        est.valid = max(est.valid, int(np.ceil(per_slice)))
         changed, changed_pd = est.observe(
-            need, cfg.exchange_headroom,
-            cfg.exchange_shrink_patience, inbound=inbound)
+            _to_ref(need, per_slice), cfg.exchange_headroom,
+            cfg.exchange_shrink_patience,
+            inbound=None if inbound is None
+            else _to_ref(inbound, per_slice))
         # a per-dest-only rung move is invisible to a "never" run's
         # baked plans — bumping the version there would re-trace every
         # fused window for a vector no plan consumes (the estimator
@@ -444,22 +496,55 @@ class ShardExchange:
                     recv_grant=int(est.recv_grant or 0),
                     peak_need=int(np.asarray(need).max(initial=0)))
 
-    def grant_for(self, site: Optional[Site]) -> Optional[int]:
+    def _estimate(self, site: Optional[Site]
+                  ) -> "Optional[_SiteEstimator]":
+        """A measured site's estimator (None: unmeasured / sizing off)."""
         if site is None or not self.engine.config.exchange_occupancy_sizing:
             return None
-        est = self.estimators.get(site)
-        return None if est is None else est.grant
+        return self.estimators.get(site)
 
-    def grants_for(self, site: Optional[Site]
+    def slice_lanes(self, m: int) -> int:
+        """Lanes per shard slice L of an m-lane batch.  A width THIS
+        plane produced keeps its exact per-shard split: it is a
+        transport shape (the n·W output of an upstream exchange) or an
+        aligned layout (n·La) — re-quantizing would shift every lane out
+        of its home chunk and re-cross traffic that is already placed.
+        Such widths are static per window / key set AND registered
+        (`_transport_widths`), so they carry no compile-churn pressure;
+        every other size — including organic batches that merely happen
+        to be n-divisible — quantizes onto the ladder, keeping the
+        compile set O(log) under drifting population."""
+        n = self.n_shards
+        return m // n if m in self._transport_widths and m % n == 0 \
+            else ladder_ceil(-(-m // n))
+
+    def _valid_lanes(self, est: "_SiteEstimator", m: int) -> float:
+        """Valid lanes a source slice of an m-lane batch is planned
+        over.  A batch built from grain calls is valid throughout, so
+        its slices hold m/n (the site's fill is 1); an upstream
+        exchange's padded output holds about as many valid lanes a
+        slice whatever its width, the most the site has seen."""
+        per_slice = m / self.n_shards
+        return max(est.fill * per_slice, min(float(est.valid), per_slice))
+
+    def grant_for(self, site: Optional[Site], m: int) -> Optional[int]:
+        """A measured site's scalar cap for an m-lane batch."""
+        est = self._estimate(site)
+        return None if est is None or est.grant is None \
+            else _scaled(est.grant, self._valid_lanes(est, m))
+
+    def grants_for(self, site: Optional[Site], m: int
                    ) -> Optional[Tuple[np.ndarray, int]]:
-        """The per-destination grant vector + receive rung for a
-        measured site, or None (unmeasured / sizing off)."""
-        if site is None or not self.engine.config.exchange_occupancy_sizing:
-            return None
-        est = self.estimators.get(site)
+        """The per-destination grant vector + receive rung for an
+        m-lane batch of a measured site, or None (unmeasured / sizing
+        off)."""
+        est = self._estimate(site)
         if est is None or est.grants is None:
             return None
-        return est.grants, int(est.recv_grant or 0)
+        valid = self._valid_lanes(est, m)
+        return (np.array([_scaled(g, valid) for g in est.grants],
+                         np.int64),
+                _scaled(est.recv_grant or 0, valid))
 
     def plan(self, m: int, site: Optional[Site] = None
              ) -> Tuple[int, int]:
@@ -474,19 +559,8 @@ class ShardExchange:
         the exchange for them entirely — fused.py `_apply_group`.)"""
         n = self.n_shards
         cfg = self.engine.config
-        # a width THIS plane produced keeps its exact per-shard split:
-        # it is a transport shape (the n·W output of an upstream
-        # exchange) or an aligned layout (n·La) — re-quantizing would
-        # shift every lane out of its home chunk and re-cross traffic
-        # that is already placed.  Such widths are static per window /
-        # key set AND registered (`_transport_widths`), so they carry
-        # no compile-churn pressure; every other size — including
-        # organic batches that merely happen to be n-divisible —
-        # quantizes onto the ladder, keeping the compile set O(log)
-        # under drifting population.
-        L = m // n if m in self._transport_widths and m % n == 0 \
-            else ladder_ceil(-(-m // n))
-        grant = self.grant_for(site)
+        L = self.slice_lanes(m)
+        grant = self.grant_for(site, m)
         if grant is not None:
             return L, min(L, grant)
         cap = min(L, pow2ceil(max(
@@ -507,7 +581,7 @@ class ShardExchange:
         mode = getattr(self.engine.config, "exchange_per_dest", "auto")
         if mode == "never":
             return ("legacy", L, cap, None)
-        pd = self.grants_for(site)
+        pd = self.grants_for(site, m)
         if pd is None:
             return ("legacy", L, cap, None)
         grants, recv = pd
@@ -533,20 +607,21 @@ class ShardExchange:
         cfg = self.engine.config
         mode = getattr(cfg, "exchange_per_dest", "auto")
 
-        def pd_sig(s):
+        def sig(s):
+            est = self._estimate(s)
+            if est is None:
+                return (s, None, None)
             # a "never" run bakes only legacy plans: the per-dest
             # vector must not churn its signature
-            if mode == "never":
-                return None
-            pd = self.grants_for(s)
-            return None if pd is None else (tuple(pd[0].tolist()), pd[1])
+            pd = None if mode == "never" or est.grants is None \
+                else (tuple(est.grants.tolist()), est.recv_grant)
+            return (s, est.grant, pd)
         return (self.engaged(),
                 bool(cfg.exchange_occupancy_sizing),
                 mode,
                 int(cfg.exchange_pad_quantum),
                 float(cfg.exchange_capacity_factor),
-                tuple((s, self.grant_for(s), pd_sig(s))
-                      for s in sorted(sites)))
+                tuple(sig(s) for s in sorted(sites)))
 
     # -- host-side shard alignment ------------------------------------------
 
@@ -864,10 +939,11 @@ class ShardExchange:
         ``(rows2, args2, mask2, dropped_count, need)`` — the dropped
         count folds into the window's device-side miss counter so a
         capacity overflow fails ``verify()`` (rollback + unfused replay)
-        instead of losing lanes, and ``need`` (int32[2n]: per-dest
-        demand maxed over sources ‖ summed over sources) rides the
-        window's xneed accumulator so steady fused traffic keeps the
-        site's occupancy estimate honest in BOTH directions.  A group
+        instead of losing lanes, and ``need`` (int32[2n + 1]: per-dest
+        demand maxed over sources ‖ summed over sources ‖ the batch's
+        valid lanes) rides the window's xneed accumulator so steady
+        fused traffic keeps the site's occupancy estimate honest in BOTH
+        directions.  A group
         whose args are not lane-aligned (slab-style handlers consuming a
         whole buffer per tick, e.g. the twitter dispatcher) passes
         through untouched — permuting rows away from such args would
@@ -876,7 +952,7 @@ class ShardExchange:
         n = self.n_shards
         if not exchangeable_args(args, m):
             return rows, args, mask, jnp.int32(0), \
-                jnp.zeros(2 * n, jnp.int32)
+                jnp.zeros(2 * n + 1, jnp.int32)
         mode, L, cap, pd = self.plan_ex(m, site=site)
         if cap == 0:
             # LEAN in-scan fast path: classification + the miss count,
@@ -908,7 +984,7 @@ class ShardExchange:
             self.trace_log.append((site, int(m), m_pad))
             self.note_transport_width(m_pad)
             return (jnp.where(local, rows_p, -1), args_p, local,
-                    dropped, jnp.zeros(2 * n, jnp.int32))
+                    dropped, jnp.zeros(2 * n + 1, jnp.int32))
         leaves, treedef, scalar_ix = _split_leaves(args, m)
         if mode == "perdest":
             caps, R = pd
@@ -920,7 +996,8 @@ class ShardExchange:
         args2 = _join_leaves(treedef, scalar_ix, leaves2)
         self.trace_log.append((site, int(m), int(rows2.shape[0])))
         self.note_transport_width(int(rows2.shape[0]))
-        return rows2, args2, mask2, stats[1], stats[3:]
+        return rows2, args2, mask2, stats[1], \
+            jnp.concatenate([stats[3:], (stats[1] + stats[2])[None]])
 
     # -- unfused-path entry (jitted dispatch; stats parked on device) --------
 
@@ -950,16 +1027,18 @@ class ShardExchange:
             cap_label = str(cap)
         fn = self._jit_cache.get(key)
         if fn is None:
+            # both cap layouts are one program name in the device trace,
+            # ``jit__exchange_kernel``: send, all_to_all, receive
             if mode == "perdest":
-                def call(rows, mask, *leaves):
+                def _exchange_kernel(rows, mask, *leaves):
                     return self._traced_perdest(
                         rows, list(leaves), mask, shard_capacity,
                         L, caps, R)
             else:
-                def call(rows, mask, *leaves):
+                def _exchange_kernel(rows, mask, *leaves):
                     return self._traced(rows, list(leaves), mask,
                                         shard_capacity, L, cap)
-            fn = jax.jit(call)
+            fn = jax.jit(_exchange_kernel)
             self._jit_cache[key] = fn
             shape = (L, shard_capacity, len(leaves))
             seen = self._seen_shapes.setdefault(shape, set())
@@ -1002,7 +1081,7 @@ class ShardExchange:
 
     def fold_stats(self, stats_host: np.ndarray,
                    site: Optional[Site] = None,
-                   scale: int = 1) -> None:
+                   scale: int = 1, width: int = 0) -> None:
         """Accumulate one drained [3 + n] or [3 + 2n] stats vector; the
         demand tail feeds the site's occupancy estimator (a [2n] tail
         splits into max-half ‖ sum-half inside ``observe_need``).
@@ -1010,12 +1089,15 @@ class ShardExchange:
         SAMPLED disengaged-mode probe (1-in-scale groups measured):
         count stats multiply up to stay an unbiased estimate comparable
         with engaged-mode exact totals; the demand tail is a peak, not
-        a sum, and never scales."""
+        a sum, and never scales: it is read per valid lane (the dropped
+        plus the delivered ones) of the ``width``-lane batch."""
         self.cross_shard_msgs += int(stats_host[0]) * scale
         self.dropped_msgs += int(stats_host[1]) * scale
         self.delivered_msgs += int(stats_host[2]) * scale
         if site is not None and len(stats_host) > 3:
-            self.observe_need(site, np.asarray(stats_host[3:]))
+            self.observe_need(site, np.asarray(stats_host[3:]),
+                              valid=int(stats_host[1]) + int(stats_host[2]),
+                              width=width)
 
     def fold_fused_shapes(self, shapes, n_ticks: int) -> None:
         """Account a fused window run's in-window exchanges (shapes were
@@ -1046,7 +1128,7 @@ class ShardExchange:
             for s in range(self.n_shards):
                 rung = ladder_ceil(int(np.ceil(
                     float(est.peak[s]) * cfg.exchange_headroom)))
-                out[s] = max(out[s], rung)
+                out[s] = max(out[s], _scaled(rung, est.valid))
         return out
 
     def cap_util_gauges(self) -> Dict[int, float]:

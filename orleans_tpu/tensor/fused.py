@@ -702,8 +702,14 @@ class FusedTickProgram:
             if (self._exchange_on and xch is not None) else []
         self._exchange_shapes = shapes
         self._site_keys: Dict[str, Tuple[str, str]] = {}
-        for site, _mi, _mo in shapes:
-            self._site_keys.setdefault(f"{site[0]}.{site[1]}", site)
+        # the narrowest batch each site exchanges in the window: its
+        # accumulated valid lanes are read against that width
+        self._site_widths: Dict[str, int] = {}
+        for site, m_in, _mo in shapes:
+            skey = f"{site[0]}.{site[1]}"
+            self._site_keys.setdefault(skey, site)
+            self._site_widths[skey] = min(
+                m_in, self._site_widths.get(skey, m_in))
         self._exchange_sites = list(self._site_keys)
         self._exchange_plan_sig = xch.plan_signature(
             list(self._site_keys.values())) \
@@ -807,12 +813,12 @@ class FusedTickProgram:
             return {}
         if self._xneed is not None:
             return self._xneed
-        # [2n]: per-dest demand maxed over sources ‖ summed over
-        # sources (the per-dest formulation's receive-rung signal) —
-        # matches apply_traced's need vector; max-merge is correct for
-        # both halves (each is a per-tick peak)
+        # [2n + 1]: per-dest demand maxed over sources ‖ summed over
+        # sources (the per-dest formulation's receive-rung signal) ‖
+        # the batch's valid lanes — matches apply_traced's need vector;
+        # max-merge is correct for every part (each is a per-tick peak)
         n = self.engine.n_shards
-        return {k: jnp.zeros(2 * n, jnp.int32)
+        return {k: jnp.zeros(2 * n + 1, jnp.int32)
                 for k in self._exchange_sites}
 
     def _fold_xneed(self) -> None:
@@ -824,10 +830,13 @@ class FusedTickProgram:
         xch = self.engine.exchange
         if not xn or xch is None:
             return
+        n = self.engine.n_shards
         for skey, vec in xn.items():
             site = self._site_keys.get(skey)
             if site is not None:
-                xch.observe_need(site, np.asarray(vec))
+                vec = np.asarray(vec)
+                xch.observe_need(site, vec[:2 * n], valid=int(vec[2 * n]),
+                                 width=self._site_widths[skey])
 
     @staticmethod
     def _scan_attr(attr_in):

@@ -127,13 +127,17 @@ def test_exchange_plan_ladder_and_clamp():
     # a measured site uses its ladder-quantized grant (headroom 1.5
     # over the observed per-destination peak), clamped to L
     site = ("RouteSink", "recv")
-    xch.observe_need(site, np.array([40, 3, 0, 0, 0, 0, 0, 0]))
+    xch.observe_need(site, np.array([40, 3, 0, 0, 0, 0, 0, 0]),
+                     valid=4096, width=4096)
     want = ladder_ceil(int(np.ceil(40 * engine.config.exchange_headroom)))
     assert xch.plan(4096, site=site) == (512, want)
     assert xch.plan(8, site=site) == (1, 1)  # clamp: cap ≤ L
+    # the same share of a batch twice as wide: twice the grant
+    assert xch.plan(8192, site=site) == (1024, 2 * want)
     # zero demand quantizes to cap 0 — the classification-only fast path
     site0 = ("RouteSink", "quiet")
-    xch.observe_need(site0, np.zeros(N_DEV, np.int64))
+    xch.observe_need(site0, np.zeros(N_DEV, np.int64), valid=4096,
+                     width=4096)
     assert xch.plan(4096, site=site0) == (512, 0)
     # the occupancy toggle is live: off → every site uses the fallback
     engine.config.exchange_occupancy_sizing = False
@@ -276,7 +280,7 @@ def test_checkpoint_defers_while_exchange_checks_parked(run):
                   "count": jnp.ones(4, jnp.int32)},
             dropped=jnp.asarray(np.array([True, False, False, False])),
             stats=jnp.asarray(np.array([1, 1, 3], np.int32)),
-            inject_tick=2))
+            width=4, inject_tick=2))
         assert e.maybe_periodic_checkpoint() == 0.0  # deferred
         assert not e._exchange_checks                # drained…
         redelivery = e.queues[("RouteSink", "recv")]
@@ -669,28 +673,95 @@ def test_estimator_grows_immediately_shrinks_with_patience():
     engine.config.exchange_shrink_patience = 3
     site = ("RouteSink", "recv")
     v0 = xch.cap_version
+    M = N_DEV * 512  # the batch every observation here is read on
     # first observation grants immediately
-    xch.observe_need(site, np.array([20] + [0] * (N_DEV - 1)))
-    g1 = xch.grant_for(site)
+    xch.observe_need(site, np.array([20] + [0] * (N_DEV - 1)),
+                     valid=M, width=M)
+    g1 = xch.grant_for(site, M)
     assert g1 == ladder_ceil(int(np.ceil(20 * 1.5)))
     assert xch.cap_version == v0 + 1
     # growth is immediate
-    xch.observe_need(site, np.array([200] + [0] * (N_DEV - 1)))
-    g2 = xch.grant_for(site)
+    xch.observe_need(site, np.array([200] + [0] * (N_DEV - 1)),
+                     valid=M, width=M)
+    g2 = xch.grant_for(site, M)
     assert g2 == ladder_ceil(int(np.ceil(200 * 1.5))) > g1
     assert xch.cap_version == v0 + 2
     # calm traffic: no shrink before patience drains
     for i in range(2):
-        xch.observe_need(site, np.array([10] + [0] * (N_DEV - 1)))
-        assert xch.grant_for(site) == g2, f"shrank after {i + 1} obs"
+        xch.observe_need(site, np.array([10] + [0] * (N_DEV - 1)),
+                         valid=M, width=M)
+        assert xch.grant_for(site, M) == g2, f"shrank after {i + 1} obs"
     # the patience-th calm drain shrinks to the windowed peak
-    xch.observe_need(site, np.array([10] + [0] * (N_DEV - 1)))
-    assert xch.grant_for(site) == ladder_ceil(int(np.ceil(10 * 1.5)))
+    xch.observe_need(site, np.array([10] + [0] * (N_DEV - 1)),
+                     valid=M, width=M)
+    g3 = xch.grant_for(site, M)
+    assert g3 == ladder_ceil(int(np.ceil(10 * 1.5)))
     assert xch.cap_version == v0 + 3
     # per-shard cap gauges quantize the all-time peak per destination
     caps = xch.cap_gauges()
     assert caps[0] == ladder_ceil(int(np.ceil(200 * 1.5)))
     assert caps[1] == 0
+    # a calm window whose rung is above half the grant does not shrink
+    # it: demand at a rung boundary must not flap the plan
+    xch.observe_need(site, np.array([120] + [0] * (N_DEV - 1)),
+                     valid=M, width=M)
+    g4 = xch.grant_for(site, M)
+    for _ in range(3):
+        xch.observe_need(site, np.array([70] + [0] * (N_DEV - 1)),
+                         valid=M, width=M)
+    assert xch.grant_for(site, M) == g4 > g3
+    assert xch.cap_version == v0 + 4
+    # the same demand out of half as many valid lanes is twice the share
+    wide = xch.grant_for(site, 2 * M)
+    xch.observe_need(site, np.array([120] + [0] * (N_DEV - 1)),
+                     valid=M // 2, width=M)
+    assert xch.grant_for(site, 2 * M) > wide
+
+
+def test_estimate_holds_across_batch_widths():
+    """A site whose ticks merge a varying number of slabs sees batches
+    of many widths.  Its estimate is a share of the valid lanes, so the
+    plan for every width is set by the first observation and never
+    moves after it: no exchange program compiles once each width has
+    run.  Even traffic puts every destination's share (1/n, times the
+    1.5 headroom) on a rung boundary, one destination a little below
+    it: noise must not move the plan."""
+    engine = _engine(initial_capacity=16 * N_DEV)
+    xch = engine.exchange
+    site = ("RouteSink", "recv")
+    rng = np.random.default_rng(25)
+    slab = 16384 * N_DEV  # slices as wide as a chip's share of a slab
+    widths = [k * slab for k in range(1, 9)]
+
+    # destination shards by key hash hold a little more or less than
+    # 1/n of the targets: one here holds 5% less
+    share = np.full(N_DEV, 1.0)
+    share[3] = 0.95
+    share /= share.sum()
+
+    def observe(m):
+        # each source slice's lanes, bound for the destination shards
+        per_src = rng.multinomial(m // N_DEV, share, size=N_DEV)
+        np.fill_diagonal(per_src, 0)  # a slice's own lanes stay home
+        xch.observe_need(site, per_src.max(axis=0), per_src.sum(axis=0),
+                         valid=m, width=m)
+
+    observe(widths[0])
+    plans = {m: xch.plan_ex(m, site=site) for m in widths}
+    version = xch.cap_version
+    for m in rng.choice(widths, size=200):
+        observe(int(m))
+    for m in widths:
+        mode, L, cap, pd = xch.plan_ex(m, site=site)
+        want = plans[m]
+        assert (mode, L, cap) == want[:3], m
+        assert (pd is None) == (want[3] is None), m
+        if pd is not None:
+            assert pd[1] == want[3][1] and tuple(pd[0]) == tuple(want[3][0])
+    assert xch.cap_version == version
+    # the plans scale with the width: twice the lanes, twice the caps
+    assert xch.plan_ex(widths[3], site=site)[2] \
+        == 2 * xch.plan_ex(widths[1], site=site)[2]
 
 
 @pytest.mark.parametrize("per_dest", ["never", "always"])
@@ -820,11 +891,13 @@ def test_cap_requantization_retraces_with_recorded_cause(run):
         rows = jnp.asarray(np.zeros(512, np.int32))
         mask = jnp.ones(512, bool)
         site = ("RouteSink", "probe_site")
-        xch.observe_need(site, np.array([4] + [0] * (N_DEV - 1)))
+        xch.observe_need(site, np.array([4] + [0] * (N_DEV - 1)),
+                         valid=512, width=512)
         xch.dispatch(arena, rows, {"v": jnp.zeros(512)}, mask,
                      site=site)
         before = e.compile_tracker.by_cause.get("bucket_growth", 0)
-        xch.observe_need(site, np.array([300] + [0] * (N_DEV - 1)))
+        xch.observe_need(site, np.array([300] + [0] * (N_DEV - 1)),
+                         valid=512, width=512)
         xch.dispatch(arena, rows, {"v": jnp.zeros(512)}, mask,
                      site=site)
         assert e.compile_tracker.by_cause["bucket_growth"] \
