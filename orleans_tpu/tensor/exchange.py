@@ -575,22 +575,33 @@ class ShardExchange:
         per-dest send segments (width ``sum(caps)``) and one receive
         rung ``R`` sized by the worst shard's total inbound —
         ``exchange_per_dest="auto"`` engages it only when that is
-        strictly narrower than the legacy layout for the measured site,
-        so symmetric demand keeps the exact legacy plan."""
+        strictly narrower than the legacy layout, for the measured
+        grants or, at an unmeasured site, for the even layout the
+        fallback cap implies."""
         L, cap = self.plan(m, site=site)
-        mode = getattr(self.engine.config, "exchange_per_dest", "auto")
+        cfg = self.engine.config
+        mode = getattr(cfg, "exchange_per_dest", "auto")
         if mode == "never":
             return ("legacy", L, cap, None)
+        n = self.n_shards
         pd = self.grants_for(site, m)
         if pd is None:
-            return ("legacy", L, cap, None)
-        grants, recv = pd
+            if mode != "auto" or not cfg.exchange_occupancy_sizing:
+                return ("legacy", L, cap, None)
+            # unmeasured: the fallback's even layout, every destination
+            # the fallback cap and the receive rung the same skew
+            # allowance over an even share of the inbound — the plan an
+            # even workload measures, so its first program is its last
+            grants = np.full(n, cap, np.int64)
+            recv = ladder_ceil(int(np.ceil(
+                cfg.exchange_capacity_factor * (n - 1) * L / n)))
+        else:
+            grants, recv = pd
         caps = np.minimum(grants, L).astype(np.int64)
         if caps.max() == 0 or cap == 0:
             # no measured cross demand: the legacy cap-0 fast path is
             # already the narrowest possible program
             return ("legacy", L, cap, None)
-        n = self.n_shards
         R = max(1, ladder_ceil(min(int(recv), n * L)))
         S = int(caps.sum())
         if mode != "always" and S + R >= 2 * n * cap:

@@ -764,6 +764,31 @@ def test_estimate_holds_across_batch_widths():
         == 2 * xch.plan_ex(widths[1], site=site)[2]
 
 
+def test_even_traffic_keeps_the_unmeasured_plan():
+    """On four shards the unmeasured plan is the even per-destination
+    layout, so a workload that spreads its lanes evenly measures the
+    plan it started with at a slice of 2^k lanes: its first exchange
+    program is its last."""
+    e = TensorEngine(mesh=_mesh(4), initial_capacity=64)
+    e.config.exchange_structured = "always"
+    xch = e.exchange
+    site = ("RouteSink", "recv")
+    rng = np.random.default_rng(4)
+    pow2 = [k * 65536 for k in (1, 2, 4, 8)]
+    before = {m: xch.plan_ex(m, site=site) for m in pow2}
+    assert all(plan[0] == "perdest" for plan in before.values())
+    for k in range(1, 9):
+        m = k * 65536
+        per_src = rng.multinomial(m // 4, [0.25] * 4, size=4)
+        np.fill_diagonal(per_src, 0)
+        xch.observe_need(site, per_src.max(axis=0), per_src.sum(axis=0),
+                         valid=m, width=m)
+    for m in pow2:
+        mode, L, cap, pd = xch.plan_ex(m, site=site)
+        assert (mode, L, cap) == before[m][:3], m
+        assert (pd[0], pd[1]) == (before[m][3][0], before[m][3][1]), m
+
+
 @pytest.mark.parametrize("per_dest", ["never", "always"])
 def test_undersized_estimate_parks_and_redelivers_under_churn(run,
                                                               per_dest):
