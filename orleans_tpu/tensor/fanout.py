@@ -11,9 +11,16 @@ whole batch of published messages expands into one flat (dst_key, args)
 tensor in a single jitted kernel.
 
 Raggedness with static shapes: per-message out-degrees are cumsum'd into
-offsets, and each of ``width`` output slots binary-searches which source
-message it belongs to (`searchsorted` over the offsets — the standard XLA
-ragged-expansion idiom).  Slots past the real total are masked and carry
+offsets, so source lane i owns the output slots [excl[i], offs[i]).
+Every lane writes its index at its first slot, one sorted scatter into
+``width`` slots, and a running max carries it forward: each slot then
+names the last lane with followers that starts at or before it, which
+is the lane whose range holds it.  A second scatter writes, at the same
+starts, the step between consecutive lanes' CSR-minus-slot offsets, and
+a running sum gives every slot its CSR edge with no per-slot gather of
+a per-lane table.  That is O(m + width) work, where a per-slot binary
+search over the offsets is O(width · log m) rounds of width-wide
+gathers.  Slots past the lanes that fit are masked and carry
 ``KEY_SENTINEL`` keys, which the engine's resolve kernel already drops.
 
 Width rule.  The CSR width is the live edge count rounded up to a lane
@@ -56,12 +63,31 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from orleans_tpu.tensor.exchange import ladder_ceil
 from orleans_tpu.tensor.vector_grain import (
     KEY_SENTINEL,
     ones_mask as _ones_mask,
 )
+
+#: row length of the running scans over output slots: one scan along a
+#: 786,432-slot axis took ~50 s to compile for a TPU v5e, rows of 1,024
+#: and a scan over the rows' ends ~1.6 s
+_SCAN_ROW = 1024
+
+
+def _running(x, scan, combine):
+    """Inclusive running ``scan`` (``lax.cummax`` or ``lax.cumsum``,
+    with its pairwise ``combine``) of int32 ``x``, in rows of
+    ``_SCAN_ROW`` and then across the rows' ends.  0 must be the
+    identity of ``combine`` over ``x``'s values."""
+    n = x.shape[0]
+    row = min(_SCAN_ROW, n)
+    y = scan(jnp.pad(x, (0, -n % row)).reshape(-1, row), axis=1)
+    carry = scan(y[:, -1])
+    carry = jnp.concatenate([jnp.zeros(1, x.dtype), carry[:-1]])
+    return combine(y, carry[:, None]).reshape(-1)[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("width",))
@@ -77,8 +103,14 @@ def _expand_kernel(csr_keys, csr_offsets, csr_dst, src_keys, valid, *,
     extends past ``width`` materializes NO slots (all-or-nothing per
     lane — a partial prefix would double-deliver on redelivery) and is
     flagged in ``src_dropped`` for the engine's park-and-redeliver
-    path.  ``width`` is static and may be shorter than the CSR."""
+    path.  ``width`` is static and may be shorter than the CSR.
+
+    Each slot finds its lane by a scatter of lane starts and a running
+    max, and its CSR edge by a scatter of offset steps and a running
+    sum (module docstring).  A masked slot's ``src_index`` is some lane
+    in range; nothing reads it."""
     n = csr_keys.shape[0]
+    m = src_keys.shape[0]
     idx = jnp.clip(jnp.searchsorted(csr_keys, src_keys), 0, n - 1)
     hit = valid & (csr_keys[idx] == src_keys)
     deg = jnp.where(hit, csr_offsets[idx + 1] - csr_offsets[idx], 0)
@@ -89,16 +121,30 @@ def _expand_kernel(csr_keys, csr_offsets, csr_dst, src_keys, valid, *,
     # [offs[i]-deg[i], offs[i]) — it fits iff offs[i] <= width
     src_dropped = hit & (deg > 0) & (offs > width)
     n_dropped = jnp.sum(src_dropped.astype(jnp.int32))
+    # The lanes that start at or before slot j are a prefix (starts
+    # never decrease; those past ``width`` fall off).  Below ``total``
+    # its last lane has followers: a zero-degree lane starts where the
+    # next lane does, and that lane comes later.  So the prefix's
+    # largest lane index, and the sum of its steps of the CSR-edge-
+    # minus-slot offset, are those of the lane whose range holds j.
+    excl = offs - deg
+    zeros = jnp.zeros(width, jnp.int32)
+    src_index = _running(
+        zeros.at[excl].max(jnp.arange(m, dtype=jnp.int32), mode="drop",
+                           indices_are_sorted=True),
+        lax.cummax, jnp.maximum)
+    step = jnp.diff(start - excl, prepend=0)
     j = jnp.arange(width, dtype=jnp.int32)
-    src_index = jnp.searchsorted(offs, j, side="right").astype(jnp.int32)
-    src_c = jnp.clip(src_index, 0, jnp.maximum(src_keys.shape[0] - 1, 0))
-    before = jnp.where(src_c > 0, offs[src_c - 1], 0)
-    e = start[src_c] + (j - before)
-    out_valid = (j < total) & (offs[src_c] <= width)
+    e = j + _running(
+        zeros.at[excl].add(step, mode="drop", indices_are_sorted=True),
+        lax.cumsum, jnp.add)
+    # the lanes that fit are a prefix (offs never decreases)
+    fit_end = jnp.max(jnp.where(offs <= width, offs, 0), initial=0)
+    out_valid = j < fit_end
     dst = jnp.where(out_valid,
                     csr_dst[jnp.clip(e, 0, max(csr_dst.shape[0] - 1, 0))],
                     KEY_SENTINEL)
-    return dst, src_c, out_valid, total, src_dropped, n_dropped
+    return dst, src_index, out_valid, total, src_dropped, n_dropped
 
 
 def _group_ranges(sorted_vals: np.ndarray):

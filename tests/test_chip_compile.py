@@ -104,6 +104,18 @@ def test_miss_keys_kernel(one_chip):
     _miss_keys_kernel.lower(i32, i32, valid, miss_buf=MISS_BUF).compile()
 
 
+def test_fanout_expand_kernel(one_chip):
+    """chirper-100k's publish round: a 16,384-lane slab expanded into
+    its 786,432-slot rung of a 3.5M-edge CSR over 100k accounts."""
+    from orleans_tpu.tensor.fanout import _expand_kernel
+
+    accounts, edges, lanes = 100_000, 3_500_032, 16_384
+    i32 = lambda n: _spec((n,), jnp.int32, one_chip)  # noqa: E731
+    _expand_kernel.lower(
+        i32(accounts), i32(accounts + 1), i32(edges), i32(lanes),
+        _spec((lanes,), jnp.bool_, one_chip), width=786_432).compile()
+
+
 def test_exchange_all_to_all_on_four_chips(topo):
     """The structured exchange's per-shard program over a 4-chip mesh:
     Presence's game-update emits (1M lanes) bucketed by destination
