@@ -37,7 +37,7 @@ import numpy as np
 
 #: unit roundoff of float32 — the per-term factor of the float bound
 F32_U = 2.0 ** -24
-#: Presence at the width bench.py drives (its --players/--games)
+#: Presence at the width of the benchmark's presence-1m configuration
 N_PLAYERS = 1_000_000
 N_GAMES = 10_000
 

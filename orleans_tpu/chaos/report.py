@@ -1,15 +1,15 @@
 """Chaos smoke runner: one seeded plan → one JSON fault/invariant report.
 
-``python -m orleans_tpu.chaos [--seed N] [--out PATH] [--repeat N]`` (or
-``bench.py --chaos-smoke``) runs the canonical short scenario on a
-3-silo ChaosCluster — storage flakes + injected CAS conflicts + one
-NaN-poisoned slab under live traffic, then partition → heal → hard-kill
-— checks all nine invariants (including the durable-state-plane
-kill-mid-traffic recovery scenario), and emits a JSON report alongside the
-BENCH_*.json artifacts.  The report carries the (seed, plan) pair and
-the deterministic trace signature, so a failing run is replayable
-exactly; ``--repeat 2`` re-runs the plan and asserts the signatures are
-identical (the reproducibility proof from the acceptance criteria).
+``python -m orleans_tpu.chaos [--seed N] [--out PATH] [--repeat N]``
+runs the canonical short scenario on a 3-silo ChaosCluster — storage
+flakes + injected CAS conflicts + one NaN-poisoned slab under live
+traffic, then partition → heal → hard-kill — checks all nine invariants
+(including the durable-state-plane kill-mid-traffic recovery scenario),
+and emits a JSON report (``CHAOS_SMOKE.json`` by default).  The report
+carries the (seed, plan) pair and the deterministic trace signature, so
+a failing run is replayable exactly; ``--repeat 2`` re-runs the plan and
+asserts the signatures are identical (the reproducibility proof from the
+acceptance criteria).
 """
 
 from __future__ import annotations

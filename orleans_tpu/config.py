@@ -112,7 +112,7 @@ class ResilienceConfig:
     layered over the same call paths."""
 
     # transient-resend backoff (exponential, full jitter); disabling is
-    # the A/B baseline bench.py --workload degraded measures against
+    # the no-backoff baseline for A/B measurement
     backoff_enabled: bool = True
     backoff_base: float = 0.02
     backoff_cap: float = 1.0
@@ -378,8 +378,8 @@ class TensorEngineConfig:
     # step/fused programs take the arena state columns as DONATED
     # inputs (jax donate_argnums), so XLA double-buffers in place and
     # back-to-back ticks never serialize on a host round-trip.  Off =
-    # the undonated serial baseline the exactness A/B replays against
-    # (bench.py --workload latency); rollback pins copy-before-donate.
+    # the undonated serial baseline the exactness A/B replays against;
+    # rollback pins copy-before-donate.
     # A live toggle re-traces step programs (cause config_toggle).
     donate_state: bool = True
     # overlapped h2d: BatchInjector.stage() (and the auto-fuser's
@@ -399,8 +399,8 @@ class TensorEngineConfig:
     # message pump — ActivationCollector.cs:37): a sweep's victims drain
     # in bounded chunks interleaved between ticks, each slice capped at
     # this host-pause budget (seconds).  <= 0 runs the whole sweep in one
-    # slice — the synchronous stop-the-world baseline the collection
-    # bench A/Bs against (bench.py --synchronous-collection).
+    # slice — the synchronous stop-the-world baseline for A/B
+    # measurement.
     # Live-reloadable.
     collection_pause_budget_s: float = 0.005
     # victims written back per chunk: bounds both a single chunk's stall
@@ -516,7 +516,7 @@ class TensorEngineConfig:
     # bound for one (destination, type, method) within a drain cycle
     # merge into ONE wire frame, so receivers see stable batch sizes
     # instead of compile-churning fragment sizes.  Off only for A/B
-    # measurement (bench.py --workload cluster publishes both sides).
+    # measurement of the receivers' compile churn.
     slab_aggregation: bool = True
     # max parked optimistic miss-checks before a forced (synchronizing)
     # drain — bounds device memory pinned by deferred delivery checks

@@ -1422,8 +1422,8 @@ class RpcFabric:
 # no-shared-disk membership path plugins/table_service.py exists for)
 # and a client DRIVER process dialing the gateway port.  Both print one
 # JSON line on stdout; the server then serves until stdin closes, so an
-# exiting parent always reaps it.  bench.py's rpc tier and the
-# tests/test_rpc.py multiprocess smoke spawn these.
+# exiting parent always reaps it.  The tests/test_rpc.py
+# multiprocess smoke spawns these.
 
 def _serve_main(args) -> int:
     import json

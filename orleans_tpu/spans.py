@@ -248,8 +248,7 @@ class TimelineRecorder:
     ``TIMELINE.json`` plus a Chrome trace-event (Perfetto) export.
 
     Everything is host bookkeeping on one deque; with ``enabled=False``
-    every entry point returns before allocating (the timeline A/B in
-    bench.py proves the envelope alongside the span plane's)."""
+    every entry point returns before allocating."""
 
     def __init__(self, silo: str, capacity: int = 4096,
                  enabled: bool = True) -> None:

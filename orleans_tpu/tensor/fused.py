@@ -215,8 +215,7 @@ class FusedTickProgram:
         # zero per-window host work.  Inside a window each tick's
         # messages complete in their own (virtual) tick, so the recorded
         # delta is 0: the fused steady state IS the zero-queue-delay
-        # operating point, and wall latency comes from seconds-per-tick
-        # (bench.py's device-ledger points measure exactly that).
+        # operating point, and wall latency comes from seconds-per-tick.
         self._ledger_on = False
         self._hist_shape: "Tuple[int, int] | None" = None
         # workload attribution (tensor/attribution.py): baked at build

@@ -20,9 +20,8 @@ never per message, never per tick.
 Tick→seconds conversion is the reader's job (``metrics.CATALOG`` records
 the unit as ticks): multiply by a seconds-per-tick measured over a whole
 run (elapsed wall / ticks run — the observation floor is paid ONCE at
-the end and amortizes to nothing).  bench.py's
-``latency_operating_points`` publishes exactly that, with no sync-floor
-subtraction, because the floor never entered the measurement.
+the end and amortizes to nothing), with no sync-floor subtraction,
+because the floor never entered the measurement.
 
 Bucket scheme (shared with metrics.Log2Histogram, base=1): bucket 0 =
 delta 0 (completed in its inject tick), bucket k = [2**(k-1), 2**k)
@@ -222,8 +221,7 @@ class DeviceLatencyLedger:
         """Device batch on the tick hot path: count the applied lanes
         (base ∧ rows resolved) straight into hist[slot, bucket(delta)].
         ONE jit dispatch, mask combine inside, scalar bucket on host —
-        the cheapest possible per-batch accounting (the <5% A/B bound in
-        bench.py --workload metrics rides on this)."""
+        the cheapest possible per-batch accounting."""
         if not self.enabled or delta < 0:
             return
         slot = self.slot_for(type_name, method)

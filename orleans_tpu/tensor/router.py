@@ -125,7 +125,7 @@ class VectorRouter:
         # reference batch-drains its per-destination send queues in
         # SocketSender/SiloMessageSender rather than writing singly).
         # Toggle (config.tensor.slab_aggregation) kept for A/B measurement
-        # — bench.py --workload cluster publishes both sides.
+        # of the receivers' compile churn.
         self.aggregate_slabs = bool(getattr(
             silo.config.tensor, "slab_aggregation", True))
         self._pending_slabs: Dict[Tuple, List[Tuple[np.ndarray, Any]]] = {}

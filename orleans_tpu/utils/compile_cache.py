@@ -1,6 +1,6 @@
 """Persistent XLA compile cache placement for the entry points.
 
-Called at the start of ``chip_smoke.py``, ``bench.py`` and
+Called at the start of ``chip_smoke.py`` and
 ``python -m orleans_tpu.host`` — never on package import, so tests and
 library users write nothing to it.  The cache's path is part of JAX's
 cache key, so it is a fixed directory of the checkout: never derived

@@ -5,9 +5,8 @@ Same workload as samples/chirper.py but one RPC per follower delivery,
 structurally the reference's execution model (reference:
 Samples/Chirper/ChirperGrains/ChirperAccount.cs:129-156 PublishMessage —
 one NewChirp call per follower awaited with WhenAll; AddFollower :235;
-NewChirp :261 with the bounded received-message cache).  Used by bench.py
-as the per-message dispatch baseline for the chirper workload and by
-tests as the host-path parity surface.
+NewChirp :261 with the bounded received-message cache).  Used by tests
+as the host-path parity surface for the chirper workload.
 """
 
 from __future__ import annotations

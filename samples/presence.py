@@ -456,7 +456,7 @@ async def run_presence_pipelined(engine, n_players: int, n_games: int,
 
     ``offered_rate=None`` estimates the highest sustainable rate from
     measured per-rung service times; the caller verifies p99 ≤ budget
-    and retries lower if the estimate overshot (bench.py does this).
+    and retries lower if the estimate overshot.
     Delivery exactness is asserted via the programs' device-side miss
     counters at the end of the run."""
     import asyncio as _asyncio
@@ -468,9 +468,9 @@ async def run_presence_pipelined(engine, n_players: int, n_games: int,
     pipeline = engine.pipeline
 
     # the rung ladder (programs + compiles + measured service times) is
-    # cached on the engine: bench.py retries this function up to 4 times
-    # per budget on one engine, and rebuilding ~6 fused programs per
-    # attempt would be almost all compile wall time on a slow rig
+    # cached on the engine: a caller retrying this function at a lower
+    # rate on one engine would otherwise rebuild ~6 fused programs per
+    # attempt, almost all compile wall time on a slow rig
     cache = getattr(engine, "_pipelined_rung_cache", None)
     if cache is not None and cache["key"] == (n_players, n_games, seed):
         rungs, service = cache["rungs"], cache["service"]

@@ -1,11 +1,10 @@
-"""Chat rooms & leaderboards — million-user scenarios riding the device
-streams plane (tensor/streams_plane.py).
+"""Chat rooms — a million-user scenario riding the device streams plane
+(tensor/streams_plane.py).
 
-Both scenarios share one shape: a small-ish set of STREAMS (chat rooms /
-leaderboards) with a large, churning SUBSCRIBER population (users /
-board members).  The reference would run these as pub-sub over grains —
-one rendezvous lookup + one grain call per (event, consumer)
-(PubSubRendezvousGrain + PersistentStreamPullingAgent); here the
+A small-ish set of STREAMS (chat rooms) with a large, churning
+SUBSCRIBER population (users).  The reference would run this as pub-sub
+over grains — one rendezvous lookup + one grain call per (event,
+consumer) (PubSubRendezvousGrain + PersistentStreamPullingAgent); here the
 subscriber adjacency lives on device as arena CSR and a whole tick's
 publishes fan out in one gather + segment reduction.
 
@@ -100,60 +99,6 @@ class ChatUserGrain(VectorGrain):
         }
 
 
-@vector_grain
-class LeaderboardGrain(VectorGrain):
-    """Stream ingress: one row per board; score posts aggregate on the
-    board and broadcast to every follower."""
-
-    rounds = field(jnp.int32, 0)
-    top_score = field(jnp.int32, 0)
-
-    @batched_method
-    @staticmethod
-    def post(state, batch: Batch, n_rows: int):
-        rows = batch.rows
-        ones = jnp.asarray(batch.mask, jnp.int32)
-        score = jnp.where(batch.mask,
-                          jnp.asarray(batch.args["score"], jnp.int32), 0)
-        return {
-            **state,
-            "rounds": state["rounds"] + seg_sum(ones, rows, n_rows),
-            "top_score": jnp.maximum(state["top_score"],
-                                     seg_max(score, rows, n_rows)),
-        }
-
-
-@vector_grain
-class BoardMemberGrain(VectorGrain):
-    """Subscriber: a user following one or more boards."""
-
-    updates = field(jnp.int32, 0)
-    best_seen = field(jnp.int32, 0)
-    checksum = field(jnp.int32, 0)
-
-    @batched_method
-    @staticmethod
-    def observe(state, batch: Batch, n_rows: int):
-        rows, args, seg = batch.rows, batch.args, batch.segments
-        ones = jnp.where(batch.mask, 1, 0).astype(jnp.int32)
-        score = jnp.asarray(args["score"], jnp.int32)
-        mix = jnp.where(batch.mask,
-                        score % _MSG_MIX
-                        + jnp.asarray(args["src_key"], jnp.int32)
-                        % _SRC_MIX, 0)
-        return {
-            **state,
-            "updates": state["updates"]
-            + seg_sum(ones, rows, n_rows, segments=seg),
-            "best_seen": jnp.maximum(
-                state["best_seen"],
-                seg_max(jnp.where(batch.mask, score, 0), rows, n_rows,
-                        segments=seg, fill=0)),
-            "checksum": state["checksum"]
-            + seg_sum(mix, rows, n_rows, segments=seg),
-        }
-
-
 # ---------------------------------------------------------------------------
 # graph construction
 # ---------------------------------------------------------------------------
@@ -161,7 +106,7 @@ class BoardMemberGrain(VectorGrain):
 def build_membership(n_streams: int, n_subscribers: int,
                      mean_memberships: float = 3.0, zipf_a: float = 1.2,
                      seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
-    """(stream_keys, sub_keys) edge arrays: room/board popularity ~ Zipf
+    """(stream_keys, sub_keys) edge arrays: room popularity ~ Zipf
     (a few huge rooms, a long tail — the power-law stress), every
     subscriber belongs to at least one stream."""
     rng = np.random.default_rng(seed)
@@ -204,16 +149,12 @@ class _HostMirror:
             self._version = self.subs.layout_version
         return self._dsts, self._srcs
 
-    def publish(self, stream_keys: np.ndarray, msg_or_score: np.ndarray,
-                kind: str = "chat") -> None:
+    def publish(self, stream_keys: np.ndarray, msg: np.ndarray) -> None:
         dsts, srcs = self._expansion(stream_keys)
-        v = msg_or_score[srcs].astype(np.int64)
+        v = msg[srcs].astype(np.int64)
         sk = stream_keys[srcs].astype(np.int64)
         np.add.at(self.received, dsts, 1)
-        if kind == "chat":
-            np.maximum.at(self.last_msg, dsts, v)
-        else:
-            np.maximum.at(self.last_msg, dsts, np.maximum(v, 0))
+        np.maximum.at(self.last_msg, dsts, v)
         np.add.at(self.checksum, dsts, v % _MSG_MIX + sk % _SRC_MIX)
 
     def evict_keys(self, keys: np.ndarray) -> None:
@@ -223,27 +164,22 @@ class _HostMirror:
         self._version = -1
 
 
-def check_chat_exact(engine, n_users: int, mirror: _HostMirror,
-                     kind: str = "chat") -> Dict[str, bool]:
+def check_chat_exact(engine, n_users: int,
+                     mirror: _HostMirror) -> Dict[str, bool]:
     """Device arenas vs the host replay — exact integer equality (the
     delivery-multiset oracle: counts + order-free checksums + max)."""
-    type_name = "ChatUserGrain" if kind == "chat" else "BoardMemberGrain"
-    f_recv = "received" if kind == "chat" else "updates"
-    f_max = "last_msg" if kind == "chat" else "best_seen"
-    arena = engine.arena_for(type_name)
+    arena = engine.arena_for("ChatUserGrain")
     users = np.arange(n_users, dtype=np.int64)
     rows, ok = arena.lookup_rows(users)
     live = ok
-    got_recv = np.asarray(arena.state[f_recv])[rows]
-    got_max = np.asarray(arena.state[f_max])[rows]
+    got_recv = np.asarray(arena.state["received"])[rows]
+    got_max = np.asarray(arena.state["last_msg"])[rows]
     got_sum = np.asarray(arena.state["checksum"])[rows]
-    exp_max = mirror.last_msg if kind == "chat" \
-        else np.maximum(mirror.last_msg, 0)
     return {
         "received_exact": bool(
             np.array_equal(got_recv[live], mirror.received[live])),
         "max_exact": bool(np.array_equal(got_max[live],
-                                         exp_max[live])),
+                                         mirror.last_msg[live])),
         "checksum_exact": bool(
             np.array_equal(got_sum[live], mirror.checksum[live])),
         "live_subscribers": int(live.sum()),
@@ -323,58 +259,6 @@ async def run_chat_load(engine, n_rooms: int = 1_000,
     return stats
 
 
-async def run_leaderboard_load(engine, n_boards: int = 512,
-                               n_members: int = 100_000,
-                               mean_follows: float = 2.0,
-                               n_ticks: int = 16, seed: int = 0,
-                               verify: bool = False) -> Dict[str, float]:
-    """Score rounds: every board posts one aggregated score per tick and
-    broadcasts it to every follower (rank-watchers)."""
-    import jax as _jax
-
-    rng = np.random.default_rng(seed)
-    subs = DeviceSubscriptions(engine, "BoardMemberGrain", "observe")
-    streams, members = build_membership(n_boards, n_members,
-                                        mean_follows, seed=seed + 1)
-    subs.subscribe_many(streams, members)
-    engine.register_subscriptions("LeaderboardGrain", "post", subs)
-    engine.arena_for("BoardMemberGrain").reserve(n_members)
-    engine.arena_for("BoardMemberGrain").resolve_rows(
-        np.arange(n_members, dtype=np.int64))
-    engine.arena_for("LeaderboardGrain").reserve(n_boards)
-    boards = np.arange(n_boards, dtype=np.int64)
-    subs.bind(boards)
-    injector = engine.make_injector("LeaderboardGrain", "post", boards)
-    mirror = _HostMirror(subs, n_members) if verify else None
-    arena = engine.arena_for("BoardMemberGrain")
-    edges = subs.edge_count
-
-    scores = [rng.integers(1, 1_000_000, n_boards).astype(np.int32)
-              for _ in range(n_ticks)]
-    t0 = time.perf_counter()
-    for t in range(n_ticks):
-        injector.stage({"score": scores[t]})
-        injector.inject()
-        await engine.drain_queues()
-        if mirror is not None:
-            mirror.publish(boards, scores[t].astype(np.int64),
-                           kind="board")
-    await engine.flush()
-    _jax.block_until_ready(arena.state["updates"])
-    elapsed = time.perf_counter() - t0
-
-    events = (n_boards + edges) * n_ticks
-    stats: Dict[str, float] = {
-        "boards": n_boards, "members": n_members, "edges": edges,
-        "ticks": n_ticks, "seconds": elapsed, "events": events,
-        "events_per_sec": events / elapsed,
-    }
-    if mirror is not None:
-        stats["oracle"] = check_chat_exact(engine, n_members, mirror,
-                                           kind="board")
-    return stats
-
-
 async def run_chat_stream_load(silo, provider_name: str = "cstream",
                                n_rooms: int = 1_000,
                                n_users: int = 100_000,
@@ -433,56 +317,4 @@ async def run_chat_stream_load(silo, provider_name: str = "cstream",
                     "pulling agent (ONE dequeue+ack transaction per "
                     "cycle) → staged slab → ChatRoomGrain.publish → "
                     "device subscription fan-out (pull-mode)",
-    }
-
-
-async def run_chat_load_fused(engine, n_rooms: int = 1_000,
-                              n_users: int = 100_000,
-                              mean_memberships: float = 3.0,
-                              n_ticks: int = 32, window: int = 16,
-                              seed: int = 0,
-                              subs: Optional[DeviceSubscriptions] = None
-                              ) -> Dict[str, float]:
-    """Chat through the FUSED tick path: the publish kernel + the pull
-    CSR expansion + the member fan-in compile into one program per
-    window (the route's offsets ride as trace constants; an adjacency
-    rebuild or live toggle re-traces, cause config_toggle)."""
-    import jax as _jax
-
-    from orleans_tpu.tensor.fused import plan_windows
-
-    subs = wire_chat(engine, n_rooms, n_users, mean_memberships, seed,
-                     subs=subs)
-    rooms = np.arange(n_rooms, dtype=np.int64)
-    prog = engine.fuse_ticks("ChatRoomGrain", "publish", rooms)
-    arena = engine.arena_for("ChatUserGrain")
-    edges = subs.edge_count
-    window, n_windows, n_ticks = plan_windows(window, n_ticks)
-
-    def stacked_for(base: int):
-        return {"msg_id": (jnp.arange(window, dtype=jnp.int32)[:, None]
-                           * np.int32(n_rooms)
-                           + jnp.arange(n_rooms, dtype=jnp.int32)[None]
-                           + np.int32(base * n_rooms))}
-
-    prog.run(stacked_for(0))  # untimed warm window (compile)
-    _jax.block_until_ready(arena.state["received"])
-    windows = [stacked_for(w + 1) for w in range(n_windows)]
-    _jax.block_until_ready(windows)
-
-    t0 = time.perf_counter()
-    for stacked in windows:
-        prog.run(stacked)
-    _jax.block_until_ready(arena.state["received"])
-    elapsed = time.perf_counter() - t0
-    misses = prog.verify()
-    if misses:  # not assert: -O must not skip exactness verification
-        raise RuntimeError(
-            f"fused chat window missed {misses} deliveries")
-
-    events = (n_rooms + edges) * n_ticks
-    return {
-        "rooms": n_rooms, "users": n_users, "edges": edges,
-        "ticks": n_ticks, "seconds": elapsed, "events": events,
-        "events_per_sec": events / elapsed, "engine": "fused",
     }

@@ -1,15 +1,13 @@
 """Workload attribution plane (tensor/attribution.py): device hot-grain
 counts + count-min sketch vs host oracles, eviction/rollback
 bit-exactness, the delta-plan hot path, HotSet/skew/SLO publication
-through silo → load publisher → dashboard, and the perfgate
-attribution family + rig machinery.
+through silo → load publisher → dashboard.
 
 Marked ``attribution`` (pytest.ini); everything runs on the CPU backend.
 """
 
 import asyncio
 import json
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -20,8 +18,6 @@ from orleans_tpu.tensor import TensorEngine
 from orleans_tpu.tensor import attribution as attr_mod
 
 pytestmark = pytest.mark.attribution
-
-REPO = Path(__file__).resolve().parent.parent
 
 
 def _engine(**cfg):
@@ -711,130 +707,3 @@ def test_dashboard_file_mode_mixed_rounds(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "hot grains:" not in out  # old round alone has no hot data
     assert "msgs" in out or "cluster" in out or out.strip()
-
-
-# ---------------------------------------------------------------------------
-# perfgate: attribution family, --all-families, rig warnings
-# ---------------------------------------------------------------------------
-
-def _baseline(tmp_path, **extra):
-    base = {
-        "metrics": {
-            "m1": {"path": "value", "value": 100.0, "tolerance": 0.3},
-        },
-        "attribution_metrics": {
-            "topk": {"path": "oracle.topk_exact", "value": 1.0,
-                     "direction": "flag"},
-        },
-        **extra,
-    }
-    p = tmp_path / "PERF_BASELINE.json"
-    p.write_text(json.dumps(base))
-    return p
-
-
-def test_perfgate_attribution_family(tmp_path):
-    from orleans_tpu.perfgate import run_gate
-
-    _baseline(tmp_path)
-    art = {"workload": "attribution", "oracle": {"topk_exact": True}}
-    (tmp_path / "ATTRIBUTION_BENCH.json").write_text(json.dumps(art))
-    v = run_gate(str(tmp_path / "PERF_BASELINE.json"),
-                 family="attribution")
-    assert v["status"] == "pass"
-    assert v["artifact"].endswith("ATTRIBUTION_BENCH.json")
-    # honored flag regression: exact→inexact always fails
-    (tmp_path / "ATTRIBUTION_BENCH.json").write_text(json.dumps(
-        {"workload": "attribution", "oracle": {"topk_exact": False}}))
-    v = run_gate(str(tmp_path / "PERF_BASELINE.json"),
-                 family="attribution")
-    assert v["status"] == "fail"
-
-
-def test_perfgate_all_families_combined(tmp_path):
-    """--all-families: one combined verdict; a failing family fails the
-    gate, a family with no usable artifact reads as an error entry."""
-    from orleans_tpu import perfgate
-
-    _baseline(tmp_path)
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"metric": "x", "value": 95.0}))
-    (tmp_path / "ATTRIBUTION_BENCH.json").write_text(json.dumps(
-        {"workload": "attribution", "oracle": {"topk_exact": True}}))
-    combined = perfgate.run_all_families(
-        str(tmp_path / "PERF_BASELINE.json"))
-    assert combined["families"]["bench"]["status"] == "pass"
-    assert combined["families"]["attribution"]["status"] == "pass"
-    # latency/multichip have no artifacts here → error entries, and the
-    # combined status reflects them (error, not silently pass)
-    assert combined["families"]["latency"]["status"] == "error"
-    assert combined["status"] == "error"
-    # a real regression beats an error in the combined status
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"metric": "x", "value": 10.0}))
-    combined = perfgate.run_all_families(
-        str(tmp_path / "PERF_BASELINE.json"))
-    assert combined["status"] == "fail"
-    # CLI: single exit code
-    import contextlib
-    import io
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        rc = perfgate.main(["--baseline",
-                            str(tmp_path / "PERF_BASELINE.json"),
-                            "--all-families"])
-    assert rc == 1
-    assert json.loads(buf.getvalue())["status"] == "fail"
-
-
-def test_perfgate_rig_warning(tmp_path):
-    """A rig mismatch WARNS (verdict rig_check + markdown note), never
-    fails; absent headers read as unknown."""
-    from orleans_tpu.perfgate import render_markdown, run_gate
-
-    rig_a = {"schema_version": 1, "jax": "0.4.37", "device_kind": "cpu",
-             "device_count": 1}
-    rig_b = {**rig_a, "device_kind": "TPU v4", "device_count": 8}
-    _baseline(tmp_path, rig=rig_a)
-    art = {"metric": "x", "value": 100.0, "rig": rig_b}
-    v = run_gate(str(tmp_path / "PERF_BASELINE.json"), artifact=art,
-                 artifact_name="a.json")
-    assert v["status"] == "pass"  # warning, not failure
-    assert v["rig_check"]["status"] == "mismatch"
-    fields = {mm["field"] for mm in v["rig_check"]["mismatches"]}
-    assert fields == {"device_kind", "device_count"}
-    assert "RIG MISMATCH" in render_markdown(v, "a.json")
-    # matching rig
-    v = run_gate(str(tmp_path / "PERF_BASELINE.json"),
-                 artifact={"metric": "x", "value": 100.0, "rig": rig_a},
-                 artifact_name="a.json")
-    assert v["rig_check"]["status"] == "match"
-    # artifact predating the header
-    v = run_gate(str(tmp_path / "PERF_BASELINE.json"),
-                 artifact={"metric": "x", "value": 100.0},
-                 artifact_name="a.json")
-    assert v["rig_check"]["status"] == "unknown"
-
-
-def test_bench_rig_header_fields():
-    import bench
-
-    rig = bench._rig_header()
-    for f in ("schema_version", "python", "jax", "jaxlib", "platform",
-              "device_kind", "device_count"):
-        assert f in rig, f
-    assert rig["device_count"] >= 1
-    assert rig["schema_version"] == bench.RIG_SCHEMA_VERSION
-
-
-def test_repo_baseline_declares_attribution_family():
-    """The checked-in baseline carries the attribution_metrics section
-    (seeded from the first smoke round) and a recorded rig, so the
-    family + rig warnings are live in CI, not just in unit tests."""
-    base = json.loads((REPO / "PERF_BASELINE.json").read_text())
-    fam = base.get("attribution_metrics", {})
-    assert fam, "attribution_metrics missing from PERF_BASELINE.json"
-    for spec in fam.values():
-        assert "path" in spec and "value" in spec
-    assert isinstance(base.get("rig"), dict) \
-        and "device_kind" in base["rig"]

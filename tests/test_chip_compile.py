@@ -17,7 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
 N_PLAYERS = 1 << 20      # PresenceGrain rows and heartbeat lanes
-N_GAMES = 10_000         # GameGrain rows (bench.py --games)
+N_GAMES = 10_000         # GameGrain rows (presence-1m's games)
 
 
 @pytest.fixture(scope="module")

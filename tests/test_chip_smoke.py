@@ -1,6 +1,6 @@
 """chip_smoke.py on the CPU: its phases at a small size against the
 NumPy replay, its refusal to run without a TPU, and the compile-cache
-placement it (with bench.py and the host) starts with."""
+placement it (with the host) starts with."""
 
 import asyncio
 import json

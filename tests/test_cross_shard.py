@@ -607,58 +607,6 @@ def test_shard_occupancy_gauge(run):
 
 
 # ---------------------------------------------------------------------------
-# satellite: perfgate multichip artifact family
-# ---------------------------------------------------------------------------
-
-def test_perfgate_multichip_family(tmp_path):
-    import json
-
-    from orleans_tpu.perfgate import newest_bench_artifact, run_gate
-
-    # opaque legacy rounds are skipped, never treated as regression-free
-    (tmp_path / "MULTICHIP_r05.json").write_text(json.dumps(
-        {"n_devices": 8, "rc": 0, "ok": True, "tail": ""}))
-    structured = {"workload": "multichip", "n_devices": 8,
-                  "aggregate_msgs_per_sec": 1000.0,
-                  "exchange": {"dropped_msgs": 0}}
-    (tmp_path / "MULTICHIP_BENCH.json").write_text(
-        json.dumps(structured))
-    found = newest_bench_artifact(str(tmp_path), family="multichip")
-    assert found is not None
-    assert found[0].endswith("MULTICHIP_BENCH.json")
-
-    baseline = {"source": "test",
-                "multichip_metrics": {
-                    "aggregate": {"path": "aggregate_msgs_per_sec",
-                                  "value": 900.0, "tolerance": 0.3,
-                                  "direction": "higher"},
-                    "dropped": {"path": "exchange.dropped_msgs",
-                                "value": 0.0, "tolerance": 0.0,
-                                "direction": "lower"}}}
-    bp = tmp_path / "PERF_BASELINE.json"
-    bp.write_text(json.dumps(baseline))
-    verdict = run_gate(str(bp), family="multichip")
-    assert verdict["status"] == "pass", verdict
-    # a driver-wrapper structured round outranks the bench fallback
-    (tmp_path / "MULTICHIP_r06.json").write_text(json.dumps(
-        {"parsed": {**structured, "aggregate_msgs_per_sec": 50.0}}))
-    verdict = run_gate(str(bp), family="multichip")
-    assert verdict["status"] == "fail"
-    assert verdict["artifact"].endswith("MULTICHIP_r06.json")
-
-    # the repo's own baseline declares the multichip family
-    repo_baseline = json.loads(
-        open("PERF_BASELINE.json").read())
-    assert repo_baseline.get("multichip_metrics"), \
-        "PERF_BASELINE.json must carry multichip tolerance bands"
-    # the never-regress contract is gated with flag semantics: fused
-    # exchange-on dropping below exchange-off can never pass again
-    beats = repo_baseline["multichip_metrics"].get(
-        "multichip_exchange_on_beats_off_at_50")
-    assert beats and beats["direction"] == "flag", beats
-
-
-# ---------------------------------------------------------------------------
 # occupancy-sized caps: estimator, churn property, re-quantization cause
 # ---------------------------------------------------------------------------
 
@@ -1045,49 +993,3 @@ def test_pre_exchange_overlap_credit(run):
                                       _sink_state(e_off, 256)[1])
 
     run(main())
-
-
-@pytest.mark.slow
-def test_multichip_bench_tier_publishes_contract(run):
-    """The structured multichip tier at plumbing scale: the artifact
-    carries the sweep, exactness at every ratio, per-shard balance, the
-    A/B toggles, and an embedded perfgate verdict — the fields the
-    driver's MULTICHIP rounds become trackable through.  Full smoke:
-    ``python bench.py --workload multichip --smoke``."""
-    import bench
-
-    stats = run(bench._multichip_tier(smoke=False,
-                                      sizes=(1024, 512, 4, 2)))
-    assert stats["workload"] == "multichip"
-    assert stats["exact_all_ratios"], stats["sweep"]
-    assert set(stats["sweep"]) == {"r0", "r10", "r50", "r90"}
-    for s in stats["sweep"].values():
-        assert s["exact_vs_unfused_replay"]
-        assert s["structured_exact_vs_unfused_replay"]
-        assert s["exchange_dropped"] == 0
-        assert len(s["per_shard_sink_occupancy"]) == 8
-        # the never-regress pair + the occupancy telemetry ride every
-        # sweep row
-        assert s["exchange_off_fused_msgs_per_sec"] > 0
-        assert 0 < s["bucket_utilization"] <= 1.0
-        assert "exchange_overlap_s" in s
-        assert isinstance(s["exchange_caps"], dict)
-    # the structured segment measures real cross traffic at 50%
-    assert stats["sweep"]["r50"]["cross_shard_msgs"] > 0
-    # headline = fused exchange-on only; the old any-engine max is the
-    # secondary field and can only be ≥ it
-    assert stats["aggregate_msgs_per_sec"] > 0
-    assert stats["aggregate_best_any_msgs_per_sec"] \
-        >= stats["aggregate_msgs_per_sec"]
-    assert "fused exchange-on" in stats["aggregate_def"].lower() \
-        or "FUSED EXCHANGE-ON" in stats["aggregate_def"]
-    assert stats["throughput_point"]["msgs_per_sec"] > 0
-    assert "exchange_speedup_at_50" in stats
-    assert "exchange_on_beats_off_at_50" in stats
-    attr = stats["exchange_attribution"]
-    assert "worst_case_cap_padding" in attr
-    assert "backend_engagement" in attr
-    assert attr["backend_engagement"][
-        "structured_unfused_msgs_per_sec_at_50"] > 0
-    assert stats["host_slab_reference"]["total_msgs_per_sec"] > 0
-    assert stats["perfgate"]["family"] == "multichip"

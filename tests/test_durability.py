@@ -656,44 +656,6 @@ def test_silo_publishes_ckpt_and_journal_metrics(run):
     run(main())
 
 
-def test_perfgate_durability_family(tmp_path):
-    """The durability perfgate family: artifact + baseline section are
-    wired like every other plane's."""
-    import json
-
-    from orleans_tpu.perfgate import FAMILIES, run_gate
-
-    assert "durability" in FAMILIES
-    prefix, section, fallback = FAMILIES["durability"]
-    assert fallback == "DURABILITY_BENCH.json"
-    artifact = {"workload": "durability",
-                "overhead": {"overhead_pct": 2.0},
-                "kill_recovery": {"exact": True, "rto_met": True},
-                "restore_scale": {"rows_per_sec": 1e6}}
-    baseline = {section: {
-        "durability_overhead_pct": {
-            "path": "overhead.overhead_pct", "value": 5.0,
-            "tolerance": 0.0, "direction": "lower"},
-        "durability_kill_exact": {
-            "path": "kill_recovery.exact", "value": 1.0,
-            "direction": "flag"},
-    }}
-    bp = tmp_path / "PERF_BASELINE.json"
-    bp.write_text(json.dumps(baseline))
-    verdict = run_gate(str(bp), artifact=artifact, family="durability")
-    assert verdict["status"] == "pass", verdict
-    artifact["kill_recovery"]["exact"] = False
-    verdict = run_gate(str(bp), artifact=artifact, family="durability")
-    assert verdict["status"] == "fail"
-
-    # repo baseline carries the seeded section
-    repo_baseline = os.path.join(os.path.dirname(__file__), "..",
-                                 "PERF_BASELINE.json")
-    with open(repo_baseline) as f:
-        data = json.load(f)
-    assert "durability_metrics" in data, \
-        "PERF_BASELINE.json must seed the durability family"
-
 # ---------------------------------------------------------------------------
 # fused fold-replay, composed recovery, warm standby (PR 18)
 # ---------------------------------------------------------------------------

@@ -119,7 +119,7 @@ def test_injector_survives_repack_on_mesh(run):
 
 def test_dryrun_entrypoint_runs_in_suite():
     """The driver's multi-chip dry run must pass in-process on the virtual
-    mesh (this is exactly what MULTICHIP_r{N}.json records)."""
+    mesh."""
     import __graft_entry__
 
     __graft_entry__.dryrun_multichip(N_DEV)

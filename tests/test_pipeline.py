@@ -469,64 +469,6 @@ def test_dashboard_latency_row_shows_budget_honored(run):
     assert row2["honored"] is False
 
 
-def test_perfgate_latency_family(tmp_path):
-    """--family latency: LATENCY_BENCH.json fallback resolution against
-    the baseline's latency_metrics section, and the flag direction —
-    honored→unhonored ALWAYS fails regardless of tolerance;
-    unhonored→honored passes."""
-    import json
-
-    from orleans_tpu.perfgate import main as gate_main, run_gate
-
-    baseline = {
-        "source": "test",
-        "latency_metrics": {
-            "p99_at_10ms": {"path": "operating_points.b010.p99_s",
-                            "value": 0.008, "tolerance": 0.5,
-                            "direction": "lower"},
-            "honored_at_10ms": {
-                "path": "operating_points.b010.honored_strict",
-                "value": 1.0, "tolerance": 99.0,  # tolerance IGNORED
-                "direction": "flag"},
-        },
-    }
-    bpath = tmp_path / "PERF_BASELINE.json"
-    bpath.write_text(json.dumps(baseline))
-
-    def artifact(honored, p99):
-        return {"workload": "latency",
-                "operating_points": {
-                    "b010": {"p99_s": p99, "honored_strict": honored}}}
-
-    (tmp_path / "LATENCY_BENCH.json").write_text(
-        json.dumps(artifact(True, 0.007)))
-    verdict = run_gate(str(bpath), family="latency")
-    assert verdict["status"] == "pass"
-    assert verdict["artifact"].endswith("LATENCY_BENCH.json")
-
-    # honored→unhonored fails even with an absurd tolerance band
-    verdict = run_gate(str(bpath), artifact=artifact(False, 0.007),
-                       family="latency")
-    assert verdict["status"] == "fail"
-    flag = [r for r in verdict["metrics"]
-            if r["name"] == "honored_at_10ms"][0]
-    assert flag["status"] == "fail"
-
-    # the CLI exits 1 on the same regression
-    apath = tmp_path / "bad.json"
-    apath.write_text(json.dumps(artifact(False, 0.007)))
-    rc = gate_main(["--baseline", str(bpath), "--artifact", str(apath),
-                    "--family", "latency"])
-    assert rc == 1
-
-    # a baseline flag of 0 (never honored) gaining honored=True passes
-    baseline["latency_metrics"]["honored_at_10ms"]["value"] = 0.0
-    bpath.write_text(json.dumps(baseline))
-    verdict = run_gate(str(bpath), artifact=artifact(True, 0.007),
-                       family="latency")
-    assert verdict["status"] == "pass"
-
-
 @pytest.mark.chaos
 def test_chaos_pipelined_engines_hold_invariants(run):
     """Chaos scenario: pipeline_depth > 1 (donated, low-latency) engines

@@ -1,16 +1,15 @@
 """Device cost plane: tick-phase profiler, compile-churn attribution,
-HBM memory ledger, deep capture, perf regression gate.
+HBM memory ledger, deep capture.
 
 The CI contracts of ISSUE 7: per-tick phase sums reconcile with measured
 tick wall time (within 10%), every tracked retrace site carries a cause
 code from the churn cause list, memory-ledger owner bytes equal the live
 column bytes exactly (and degrade silently to self-accounting where
 ``device.memory_stats()`` is absent — the CPU backend these tests run
-on), triggered captures reference their trace dirs from the flight
-recorder, and the perfgate renders pass/fail/tolerance verdicts.
+on), and triggered captures reference their trace dirs from the flight
+recorder.
 """
 
-import json
 import re
 import warnings
 from pathlib import Path
@@ -717,140 +716,3 @@ def test_explicit_capture_profile_management_call(run, tmp_path):
             await silo.stop(graceful=False)
 
     run(main())
-
-
-# ---------------------------------------------------------------------------
-# perf regression gate
-# ---------------------------------------------------------------------------
-
-BASELINE = {
-    "source": "unit",
-    "metrics": {
-        "throughput": {"path": "value", "value": 1000.0,
-                       "tolerance": 0.2, "direction": "higher"},
-        "p99": {"path": "latency.p99_s", "value": 0.1,
-                "tolerance": 0.5, "direction": "lower"},
-    },
-}
-
-
-def test_perfgate_pass_fail_and_tolerance_edges():
-    from orleans_tpu import perfgate
-
-    ok = perfgate.evaluate(BASELINE, {"value": 990.0,
-                                      "latency": {"p99_s": 0.12}})
-    assert ok["status"] == "pass" and ok["failed"] == 0
-
-    # exactly on the band edge passes; just past it fails
-    edge = perfgate.evaluate(BASELINE, {"value": 800.0,
-                                        "latency": {"p99_s": 0.15}})
-    assert edge["status"] == "pass"
-    fail = perfgate.evaluate(BASELINE, {"value": 799.0,
-                                        "latency": {"p99_s": 0.12}})
-    assert fail["status"] == "fail"
-    assert [r["name"] for r in fail["metrics"]
-            if r["status"] == "fail"] == ["throughput"]
-
-    # a lower-is-better regression fails in the other direction, and an
-    # IMPROVEMENT (lower latency / higher throughput) never fails
-    slow = perfgate.evaluate(BASELINE, {"value": 5000.0,
-                                        "latency": {"p99_s": 0.16}})
-    assert slow["status"] == "fail"
-    better = perfgate.evaluate(BASELINE, {"value": 9999.0,
-                                          "latency": {"p99_s": 0.001}})
-    assert better["status"] == "pass"
-
-
-def test_perfgate_missing_metrics_and_strictness():
-    from orleans_tpu import perfgate
-
-    v = perfgate.evaluate(BASELINE, {"value": 1000.0})
-    assert v["status"] == "pass" and v["missing"] == 1
-    strict = perfgate.evaluate(BASELINE, {"value": 1000.0},
-                               strict_missing=True)
-    assert strict["status"] == "fail"
-
-
-def test_perfgate_empty_baseline_is_error_not_vacuous_pass(tmp_path):
-    """A baseline checking NOTHING (empty/missing 'metrics') must read
-    as broken — a silently-unguarding gate is the failure mode the gate
-    exists to prevent (review finding)."""
-    from orleans_tpu import perfgate
-
-    for bad in ({"metrics": {}}, {"metric": BASELINE["metrics"]}):
-        v = perfgate.evaluate(bad, {"value": 1000.0})
-        assert v["status"] == "error" and v["checked"] == 0
-    base = tmp_path / "empty.json"
-    base.write_text(json.dumps({"metrics": {}}))
-    art = tmp_path / "BENCH_r09.json"
-    art.write_text(json.dumps({"parsed": {"value": 1.0}}))
-    rc = perfgate.main(["--baseline", str(base), "--artifact", str(art)])
-    assert rc == 2
-
-
-def test_perfgate_unwraps_driver_artifacts():
-    from orleans_tpu import perfgate
-
-    assert perfgate.unwrap_artifact(
-        {"parsed": {"value": 1.0}}) == {"value": 1.0}
-    # the BENCH_r05 shape: truncated capture, parsed null — unusable,
-    # never "no regressions"
-    assert perfgate.unwrap_artifact({"parsed": None, "tail": "..."}) is None
-    assert perfgate.unwrap_artifact({"value": 1.0}) == {"value": 1.0}
-    assert perfgate.unwrap_artifact("junk") is None
-
-
-def test_perfgate_cli_and_markdown(tmp_path):
-    from orleans_tpu import perfgate
-
-    base = tmp_path / "PERF_BASELINE.json"
-    base.write_text(json.dumps(BASELINE))
-    art = tmp_path / "BENCH_r07.json"
-    art.write_text(json.dumps(
-        {"parsed": {"value": 950.0, "latency": {"p99_s": 0.11}}}))
-    md = tmp_path / "gate.md"
-    rc = perfgate.main(["--baseline", str(base), "--artifact", str(art),
-                        "--markdown", str(md)])
-    assert rc == 0
-    text = md.read_text()
-    assert "PASS" in text and "throughput" in text
-
-    art.write_text(json.dumps({"parsed": {"value": 10.0}}))
-    rc = perfgate.main(["--baseline", str(base), "--artifact", str(art)])
-    assert rc == 1
-
-    art.write_text(json.dumps({"parsed": None, "tail": "trunc"}))
-    rc = perfgate.main(["--baseline", str(base), "--artifact", str(art)])
-    assert rc == 2  # unusable artifact is an error, not a pass
-
-    # a malformed baseline is a clean exit-2 JSON error, never a
-    # traceback (review finding)
-    base.write_text("{not json")
-    art.write_text(json.dumps({"parsed": {"value": 1000.0}}))
-    rc = perfgate.main(["--baseline", str(base), "--artifact", str(art)])
-    assert rc == 2
-
-
-def test_repo_baseline_is_valid_and_covers_bench_paths():
-    """The checked-in PERF_BASELINE.json parses, every entry is
-    well-formed, and an artifact holding each banded path at its
-    baseline value passes the gate the profile smoke runs."""
-    from orleans_tpu import perfgate
-
-    root = Path(__file__).resolve().parent.parent
-    baseline = json.loads((root / "PERF_BASELINE.json").read_text())
-    assert baseline["metrics"]
-    for name, spec in baseline["metrics"].items():
-        assert spec["direction"] in ("higher", "lower"), name
-        assert 0.0 < spec["tolerance"] < 1.0, name
-        assert spec["value"] > 0, name
-    artifact: dict = {}
-    for spec in baseline["metrics"].values():
-        *parents, leaf = spec["path"].split(".")
-        node = artifact
-        for key in parents:
-            node = node.setdefault(key, {})
-        node[leaf] = spec["value"]
-    v = perfgate.evaluate(baseline, perfgate.unwrap_artifact(
-        {"parsed": artifact}))
-    assert v["status"] == "pass" and v["missing"] == 0

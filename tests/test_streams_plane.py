@@ -2,15 +2,13 @@
 arena-CSR, pull-mode scatter-free fan-in, churn under eviction and slot
 reuse, overflow park-and-redeliver (the satellite's DeviceFanout
 contract included), the batched sqlite dequeue/ack pipeline, fused
-threading + live-toggle re-trace, the pub/sub mirror, metrics
-publication, and the perfgate streams family.
+threading + live-toggle re-trace, the pub/sub mirror, and metrics
+publication.
 
 Marked ``streams`` (pytest.ini); everything runs on the CPU backend.
 """
 
 import asyncio
-import json
-from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -22,8 +20,6 @@ from orleans_tpu.tensor import DeviceSubscriptions, TensorEngine
 from orleans_tpu.tensor.vector_grain import seg_max, seg_sum
 
 pytestmark = pytest.mark.streams
-
-REPO = Path(__file__).resolve().parent.parent
 
 
 def _engine(**cfg):
@@ -642,7 +638,7 @@ def test_twitter_grouped_bit_exact_vs_ungrouped(run):
 
 
 # ---------------------------------------------------------------------------
-# metrics + perfgate
+# metrics
 # ---------------------------------------------------------------------------
 
 def test_stream_metrics_declared_and_collected(run):
@@ -680,38 +676,3 @@ def test_stream_metrics_declared_and_collected(run):
 
     run(main())
 
-
-def test_perfgate_streams_family(run):
-    from orleans_tpu.perfgate import FAMILIES, run_gate
-
-    assert "streams" in FAMILIES
-    artifact = {
-        "workload": "streams",
-        "value": 13_000_000.0,
-        "leaderboards": {"events_per_sec": 600_000.0},
-        "chat_churn": {"all_exact": True},
-        "overhead_ab": {"overhead_pct": 0.5},
-        "stream_fed": {"msgs_per_sec": 4_000_000.0},
-        "twitter": {"msgs_per_sec": 50_000_000.0,
-                    "grouped_vs_ungrouped_exact": True},
-    }
-    verdict = run_gate(str(REPO / "PERF_BASELINE.json"),
-                       artifact=artifact, artifact_name="(test)",
-                       family="streams")
-    assert verdict["status"] == "pass", verdict
-    # an exactness regression ALWAYS fails (flag direction)
-    artifact["chat_churn"]["all_exact"] = False
-    verdict = run_gate(str(REPO / "PERF_BASELINE.json"),
-                       artifact=artifact, artifact_name="(test)",
-                       family="streams")
-    assert verdict["status"] == "fail"
-
-
-def test_repo_baseline_declares_streams_family():
-    data = json.loads((REPO / "PERF_BASELINE.json").read_text())
-    m = data["streams_metrics"]
-    assert m["streams_delivery_exact"]["direction"] == "flag"
-    assert m["streams_overhead_pct"]["tolerance"] == 0.0
-    # the stream_fed floor sits at or above the >=5x-of-r05 bar
-    sf = m["streams_stream_fed_msgs_per_sec"]
-    assert sf["value"] * (1 - sf["tolerance"]) >= 5 * 510_066.1 * 0.999
