@@ -214,7 +214,15 @@ class GrainArena:
         self.compact_fragmentation = 0.75
         self._sorted_keys = np.empty(0, dtype=np.int64)
         self._sorted_rows = np.empty(0, dtype=np.int32)
+        # dense key→row mirror (-1 where no grain lives), rebuilt with the
+        # sorted one when the key space is dense enough (_dense_slots);
+        # None = lookups binary-search the sorted mirror instead
+        self._dense: Optional[np.ndarray] = None
         self._dirty = False
+        # keys looked up on each path (lookup_rows): which one a workload
+        # takes.  An empty arena answers without either.
+        self.dense_lookup_keys = 0
+        self.sorted_lookup_keys = 0
         self.live_count = 0
         # host-side last use: updated by host-key resolution
         self.last_use_tick = np.zeros(self.capacity, dtype=np.int64)
@@ -382,6 +390,12 @@ class GrainArena:
         order = np.argsort(keys, kind="stable")
         self._sorted_keys = keys[order]
         self._sorted_rows = rows[order]
+        slots = self._dense_slots()
+        if slots:
+            self._dense = np.full(slots, -1, dtype=np.int32)
+            self._dense[self._sorted_keys] = self._sorted_rows
+        else:
+            self._dense = None
         self._dirty = False
         self._dev_index_stale = True
         self._dev_dense_stale = True
@@ -432,35 +446,40 @@ class GrainArena:
         return self._dev_sorted_keys, self._dev_sorted_rows
 
     # dense direct-map mirror: for SMALL integer key spaces the directory
-    # collapses further, from a binary search to one gather — measured
-    # ~80ms/tick of searchsorted at 1M messages becomes ~1ms.  Worth 4
-    # bytes per key-space slot while max_key stays within the bound.
+    # collapses further, from a binary search to one gather — on the host
+    # (lookup_rows) and on the device (dense_index) alike.  Worth 4 bytes
+    # per key-space slot while max_key stays within the bound.
     DENSE_KEY_BOUND = 1 << 23  # 8M slots = 32MB ceiling
 
-    def dense_index(self):
-        """key→row as a dense device array (or None when the key space is
-        too wide/sparse to afford it).  rows[key] == -1 for unseen keys."""
-        if self._dirty:
-            self._rebuild_index()
+    def _dense_slots(self) -> int:
+        """Length of the dense mirror over the current index (the key
+        space padded to a power of two, so the device copy re-traces
+        rarely), or 0 when keys are negative, reach DENSE_KEY_BOUND, or
+        occupy too little of their space to afford it."""
         if len(self._sorted_keys) == 0:
-            return None
+            return 0
         max_key = int(self._sorted_keys[-1])
         if int(self._sorted_keys[0]) < 0 or max_key >= self.DENSE_KEY_BOUND:
-            return None
+            return 0
         size = max_key + 1
         # sparsity guard: a handful of grains with one huge key must not
         # buy a multi-MB rebuild per activation — dense only pays when the
         # key space is reasonably occupied (or trivially small)
         if size > max(4 * max(1, self.live_count), 65536):
+            return 0
+        return 1 << (size - 1).bit_length()
+
+    def dense_index(self):
+        """key→row as a dense device array (or None when the key space is
+        too wide/sparse to afford it).  rows[key] == -1 for unseen keys:
+        the upload of the host mirror ``lookup_rows`` reads."""
+        if self._dirty:
+            self._rebuild_index()
+        if self._dense is None:
             return None
-        if not self._dev_dense_stale and self._dev_dense is not None \
-                and self._dev_dense.shape[0] >= size:
+        if not self._dev_dense_stale and self._dev_dense is not None:
             return self._dev_dense
-        # pad to the next power of two so growth re-traces rarely
-        alloc = 1 << (size - 1).bit_length()
-        dense = np.full(alloc, -1, dtype=np.int32)
-        dense[self._sorted_keys] = self._sorted_rows
-        dd = jnp.asarray(dense)
+        dd = jnp.asarray(self._dense)
         if self.sharding is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             dd = jax.device_put(
@@ -507,12 +526,23 @@ class GrainArena:
         return self._dev_wide
 
     def lookup_rows(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """Vectorized lookup; returns (rows int32, found bool)."""
+        """Vectorized lookup; returns (rows int32, found bool).  One
+        gather from the dense mirror where the arena keeps one, else a
+        binary search over the sorted mirror: the same answers."""
         if self._dirty:
             self._rebuild_index()
         if len(self._sorted_keys) == 0:
             return (np.full(len(keys), -1, np.int32),
                     np.zeros(len(keys), bool))
+        keys = np.asarray(keys)
+        dense = self._dense
+        if dense is not None and keys.dtype.kind == "i":
+            self.dense_lookup_keys += len(keys)
+            inside = (keys >= 0) & (keys < len(dense))
+            rows = np.where(inside, dense.take(keys, mode="clip"),
+                            np.int32(-1))
+            return rows, rows >= 0
+        self.sorted_lookup_keys += len(keys)
         idx = np.searchsorted(self._sorted_keys, keys)
         idx = np.minimum(idx, len(self._sorted_keys) - 1)
         found = self._sorted_keys[idx] == keys

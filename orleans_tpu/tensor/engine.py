@@ -2821,6 +2821,11 @@ class TensorEngine:
             "pipeline": self.pipeline.snapshot(),
             "autofuse": self.autofuser.snapshot(),
             "arenas": {name: a.live_count for name, a in self.arenas.items()},
+            # host key→row lookups per arena, by path (arena.lookup_rows)
+            "lookup_keys": {name: {"dense_lookup_keys": a.dense_lookup_keys,
+                                   "sorted_lookup_keys":
+                                       a.sorted_lookup_keys}
+                            for name, a in self.arenas.items()},
             "evicted": sum(a.evicted_count for a in self.arenas.values()),
             "restored": sum(a.restored_count for a in self.arenas.values()),
             # live migration (migrate_keys): batched moves + grains
